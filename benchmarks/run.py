@@ -111,6 +111,8 @@ def main_serve(argv):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if len(sys.argv) > 1 and sys.argv[1] == "serve":
         return main_serve(sys.argv[2:])
     ap = argparse.ArgumentParser()

@@ -115,6 +115,9 @@ ORDER_EDGES: Tuple[Tuple[str, str, Optional[str]], ...] = (
     ("server.pump_lock", "engine.cycle_lock", None),
     ("server.cond", "router.lock", None),
     ("router.lock", "engine.qlock", None),
+    # session picks and folds read the store's version vector under its
+    # node lock (the next fold donates the arena)
+    ("router.lock", "cluster.node_lock", None),
     ("engine.cycle_lock", "engine.qlock", None),
     ("engine.cycle_lock", "cluster.node_lock", None),
     ("engine.cycle_lock", "router.lock", "on_ready"),

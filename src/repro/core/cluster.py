@@ -55,8 +55,8 @@ from repro.core.faas import (FunctionSpec, VectorCodec,
 from repro.core.keygroup import KeygroupSpec, arena_new
 from repro.core.naming import NamingService
 from repro.core.network import FaultPlane, NetworkModel, paper_topology
-from repro.core.store import (Store, arena_clone, donation_enabled,
-                              merge_snapshots_fused, store_assign_slots)
+from repro.core.store import (Store, arena_clone, merge_snapshots_fused,
+                              store_assign_slots)
 from repro.core.versioning import MAX_NODES
 
 
@@ -229,12 +229,13 @@ class Cluster:
             return
         if existing:
             # replicate current contents from any live replica — as a
-            # CLONE: replicas must never share arena buffers, or a donated
-            # fold at one node would invalidate the other's store (TPU/GPU)
+            # CLONE taken under its lock: replicas must never share arena
+            # buffers, or a donated fold at one node would invalidate the
+            # other's store
             src = next(iter(existing))
             with self.nodes[src].lock:
-                snapshot = self.nodes[src].stores[spec.name]
-            nd.stores[spec.name] = arena_clone(snapshot)
+                snapshot = arena_clone(self.nodes[src].stores[spec.name])
+            nd.stores[spec.name] = snapshot
         else:
             nd.stores[spec.name] = self.blank_arena(spec.name, spec)
         self.naming.add_replica(spec.name, node)
@@ -541,14 +542,10 @@ class Cluster:
         if spec.policy != ReplicationPolicy.REPLICATED:
             return
         with self.nodes[source].lock:
-            snapshot = self.nodes[source].stores[kg]
-            if donation_enabled():
-                # a queued snapshot must never alias the live arena: the
-                # source's next fold and the target's fused merge DONATE
-                # their arena argument on TPU/GPU, which would invalidate
-                # every queued reference.  On CPU donation is a no-op and
-                # the immutable arena is shared for free.
-                snapshot = arena_clone(snapshot)
+            # a queued snapshot must never alias the live arena: the
+            # source's next fold and the target's fused merge DONATE their
+            # arena argument, which would invalidate every queued reference
+            snapshot = arena_clone(self.nodes[source].stores[kg])
         nbytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                      for x in snapshot[:4])
         epoch = self._epochs.get(kg, 0)
@@ -784,7 +781,12 @@ class Cluster:
 
     # -------------------------------------------------------------- debugging
     def store_of(self, kg: str, node: str) -> Store:
-        return self.nodes[node].stores[kg]
+        """A clone of ``node``'s replica of ``kg``, taken under the node
+        lock: the live arena is donated by the next fold or merge, so a
+        reference to it would die under the caller."""
+        nd = self.nodes[node]
+        with nd.lock:
+            return arena_clone(nd.stores[kg])
 
     def flush_replication(self, t: float = float("inf")) -> None:
         for n in self.nodes:

@@ -365,6 +365,11 @@ class BatchedInvocationEngine:
         self.trace_folds = False
         self.fold_trace: List[Tuple[str, int]] = []
         self._trace_lock = lockdep.make_lock("engine.trace_lock")
+        # what failed flush cycles raised, newest last (appended under
+        # _cycle_lock): a serving loop counts and swallows these under the
+        # at-most-once contract, so this is where a caller reads why
+        self.errors: "collections.deque[BaseException]" = collections.deque(
+            maxlen=16)
 
     def _hop_ms(self, client: str, node: str, payload_bytes: int) -> float:
         key = (client, node, payload_bytes)
@@ -754,7 +759,9 @@ class BatchedInvocationEngine:
                     self._ready.update(out)
                 # the lowest-seal-sequence failure: window errors in window
                 # order first, then the failing wave's earliest batch
-                raise min(run.errors)[1]
+                err = min(run.errors, key=lambda e: e[0])[1]
+                self.errors.append(err)
+                raise err
             return out
 
     def _finalize_ready(self, frames: List[_Frame]) -> bool:

@@ -37,8 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.store import (Store, donate_store_argnums, kv_delete, kv_get,
-                              kv_scan, kv_set, store_select)
+from repro.core.store import (Store, kv_delete, kv_get, kv_scan, kv_set,
+                              store_select)
 from repro.core.versioning import fnv1a
 
 
@@ -293,14 +293,14 @@ def compile_batched_handler(spec: FunctionSpec, node_id: int,
         # never materialises a batched arena
         return jax.vmap(lambda x: pure(store, clock, x)[2])(xs)
 
-    # donate the arena through the fold on backends where donation is
-    # real: XLA reuses the input buffers for the output store, so warm
-    # folds stop allocating a fresh arena per dispatch.  The caller's
-    # reference (nd.stores[kg]) dies with the dispatch — every snapshot
-    # that outlives it must be a clone (see cluster._schedule_replication
-    # and docs/batched_engine.md "Device-resident store").  jit_map is
-    # NOT donated: it hands the caller's own store refs back.
-    jit_scan = jax.jit(scanned, donate_argnums=donate_store_argnums())
+    # donate the arena through the fold: XLA reuses the input buffers for
+    # the output store, so warm folds stop allocating a fresh arena per
+    # dispatch.  The caller's reference (nd.stores[kg]) dies with the
+    # dispatch — every snapshot that outlives it must be a clone (see
+    # cluster._schedule_replication and docs/batched_engine.md
+    # "Device-resident store").  jit_map is NOT donated: it hands the
+    # caller's own store refs back.
+    jit_scan = jax.jit(scanned, donate_argnums=(0,))
     jit_map = jax.jit(mapped)
 
     def bstep(store, clock, xs, valid, independent: bool = False):

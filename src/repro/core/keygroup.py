@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.configs.base import ReplicationPolicy
 from repro.core import crdt
 from repro.core.store import Store, merge_stores, store_new
+from repro.kernels.enoki_merge.kernel import check_merge_width
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +43,11 @@ class KeygroupSpec:
     merge: str = "lww"            # lww | mean | max | diloco
     # owner node for PEER_FETCH / CLOUD_CENTRAL placements
     owner: Optional[str] = None
+
+    def __post_init__(self):
+        # replicas merge row tiles in VMEM: refuse a width the merge
+        # kernel cannot hold now, not at the first merge on a chip
+        check_merge_width(self.value_width, self.dtype)
 
 
 def arena_new(spec: KeygroupSpec, num_nodes: int) -> Store:

@@ -60,6 +60,7 @@ import math
 import threading
 from typing import Dict, List, Optional
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import lockdep
@@ -148,9 +149,10 @@ class Router:
         self._claimed: Dict[int, InvokeResult] = {}
         # guards sessions/_inflight/_hedges; held for host-side folds only,
         # never across an engine dispatch — pump/hedge submits release it
-        # first, so router.lock nests only engine.qlock (and, mid-cycle,
-        # is itself taken under the cycle lock on the on_ready delivery
-        # path).  Declared in repro/analysis/lock_order.py
+        # first, so router.lock nests only engine.qlock and a store node's
+        # lock for its version vector (and, mid-cycle, is itself taken
+        # under the cycle lock on the on_ready delivery path).  Declared
+        # in repro/analysis/lock_order.py
         self._lock = lockdep.make_rlock("router.lock")
 
     # ------------------------------------------------------------------ picks
@@ -219,9 +221,14 @@ class Router:
         to the owner replica."""
         kg, store_node, _ = self.cluster._resolve_placement(spec, node)
         snd = self.cluster.nodes[store_node]
-        if kg not in snd.stores:
-            return False
-        return session.can_read_from(np.asarray(snd.stores[kg].vv))
+        # copy on the device under the node lock (the next fold donates
+        # the arena); wait for the in-flight fold only after releasing it
+        with snd.lock:
+            store = snd.stores.get(kg)
+            if store is None:
+                return False
+            vv = jnp.copy(store.vv)
+        return session.can_read_from(np.asarray(vv))
 
     def _session(self, session_id: Optional[str]) -> Optional[Session]:
         if session_id is None:
@@ -281,16 +288,20 @@ class Router:
         if kg is None:
             return
         snd = self.cluster.nodes[store_node]
-        if kg not in snd.stores:
-            return
-        session.observe_read(np.asarray(snd.stores[kg].vv))
         wrote = any(k in ("set", "delete") for k, _ in res.kv_ops)
+        # as in _satisfies: a device copy under the node lock
+        with snd.lock:
+            store = snd.stores.get(kg)
+            if store is None:
+                return
+            vv, clock = jnp.copy(store.vv), snd.clock
+        session.observe_read(np.asarray(vv))
         if wrote:
             # the write's version stamp carries the SERVING node's id (the
             # handler is compiled with it) but the clock that advanced is
             # the STORE node's — the pair the store's vv actually recorded
             session.observe_write(self.cluster.nodes[res.node].node_id,
-                                  int(snd.clock))
+                                  int(clock))
 
     # ---------------------------------------------------------------- batched
     def submit(self, fn_name: str, x, t_send: float = 0.0,

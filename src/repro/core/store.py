@@ -275,24 +275,6 @@ merge_stores_jit = jax.jit(merge_stores)
 # Device-resident merge path: slot-aligned arenas + fused multi-way merge
 # ---------------------------------------------------------------------------
 
-def donation_enabled() -> bool:
-    """Whether jit buffer donation is real on this backend.
-
-    XLA honours ``donate_argnums`` on TPU/GPU and silently ignores it on
-    CPU, so the serving stack only pays for the defensive snapshot clones
-    donation requires (queued snapshots must never alias a donated live
-    arena — see cluster._schedule_replication) where donation actually
-    reuses buffers."""
-    return jax.default_backend() in ("tpu", "gpu")
-
-
-def donate_store_argnums() -> tuple:
-    """``donate_argnums`` for entry points whose argument 0 is the arena
-    being folded/merged into (see faas.compile_batched_handler and
-    merge_many_fn)."""
-    return (0,) if donation_enabled() else ()
-
-
 @jax.jit
 def arena_clone(store: Store) -> Store:
     """Deep-copy an arena into fresh device buffers.
@@ -301,15 +283,6 @@ def arena_clone(store: Store) -> Store:
     or shared across nodes must be a clone, never a live reference to an
     arena a later fold/merge may donate."""
     return jax.tree.map(jnp.copy, store)
-
-
-def _merge_rows_tile(slots: int) -> int:
-    # largest divisor of the arena size <= 256: enoki_merge_rows requires
-    # the tile to divide the row count exactly
-    for tile in range(min(256, slots), 0, -1):
-        if slots % tile == 0:
-            return tile
-    return 1
 
 
 def merge_stores_aligned(a: Store, b: Store) -> Store:
@@ -332,10 +305,8 @@ def merge_stores_aligned(a: Store, b: Store) -> Store:
     keygroup and anything else takes the ``merge_stores`` fallback.
     """
     take_b = b.versions > a.versions
-    values, versions = enoki_merge_rows(
-        a.values, a.versions, b.values, b.versions,
-        rows_tile=_merge_rows_tile(a.slots),
-        interpret=jax.default_backend() != "tpu")
+    values, versions = enoki_merge_rows(a.values, a.versions,
+                                        b.values, b.versions)
     return Store(
         keys=jnp.where(take_b, b.keys, a.keys),
         values=values,
@@ -351,7 +322,7 @@ def merge_many_fn(aligned: bool):
     arena with ONE device dispatch (``lax.scan`` over the stacked
     snapshots).  jit's cache keys on the pytree structure, so each
     (aligned, K, geometry) combination traces once.  The accumulator is
-    donated on backends where donation is real."""
+    donated: its caller's reference dies with the dispatch."""
     body = merge_stores_aligned if aligned else merge_stores
 
     def many(acc: Store, snaps) -> Store:
@@ -360,7 +331,7 @@ def merge_many_fn(aligned: bool):
                               acc, stacked)
         return out
 
-    return jax.jit(many, donate_argnums=donate_store_argnums())
+    return jax.jit(many, donate_argnums=(0,))
 
 
 # K is padded up to a small bucket set so warm delivery never sees a new
@@ -441,9 +412,19 @@ def store_contents(store: Store) -> dict:
 
 
 def stores_equal(a: Store, b: Store) -> bool:
-    """Exact equality of two arenas as REPLICAS: same live contents, same
-    versions, same version vector — slot layout ignored (merge order may
-    permute slots without changing what any read observes).  The
-    determinism checks of the parallel pump compare stores with this."""
-    va, vb = jax.device_get(a.vv), jax.device_get(b.vv)
-    return bool((va == vb).all()) and store_contents(a) == store_contents(b)
+    """Exact equality of two arenas as REPLICAS: same occupied keys with
+    the same versions, lengths and value rows, same version vector — slot
+    layout ignored (merge order may permute slots without changing what
+    any read observes).  The determinism checks of the parallel pump
+    compare stores with this.  Vectorized: one sort per arena, so
+    deployment-sized arenas compare in a few host passes."""
+    ha, hb = jax.device_get(a), jax.device_get(b)
+    if not np.array_equal(ha.vv, hb.vv):
+        return False
+    rows = []
+    for h in (ha, hb):
+        live = np.flatnonzero(h.keys)
+        rows.append(live[np.argsort(h.keys[live], kind="stable")])
+    ra, rb = rows
+    return len(ra) == len(rb) and all(
+        np.array_equal(x[ra], y[rb]) for x, y in zip(ha[:4], hb[:4]))

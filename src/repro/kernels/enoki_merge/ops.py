@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -9,18 +10,16 @@ import jax.numpy as jnp
 from repro.kernels.enoki_merge.kernel import enoki_merge_rows
 
 
-@functools.partial(jax.jit, static_argnames=("rows_tile", "interpret"))
-def enoki_merge(a_val, a_ver, b_val, b_ver, *, rows_tile: int = 256,
-                interpret: bool = None):
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return enoki_merge_rows(a_val, a_ver, b_val, b_ver,
-                            rows_tile=rows_tile, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def enoki_merge(a_val, a_ver, b_val, b_ver, *,
+                interpret: Optional[bool] = None):
+    return enoki_merge_rows(a_val, a_ver, b_val, b_ver, interpret=interpret)
 
 
 def merge_flat_keygroup(a_flat: jnp.ndarray, a_ver: jnp.ndarray,
                         b_flat: jnp.ndarray, b_ver: jnp.ndarray,
-                        row_width: int = 1024, interpret: bool = None):
+                        row_width: int = 1024,
+                        interpret: Optional[bool] = None):
     """LWW-merge two flat replicas (N,) with per-row versions.
 
     Row-granularity contract: versions guard ``row_width`` payload

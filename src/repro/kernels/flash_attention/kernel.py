@@ -84,8 +84,6 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
     assert Sq % bq == 0 and Skv % bk == 0, (Sq, bq, Skv, bk)
     nq, nk = Sq // bq, Skv // bk
     from jax.experimental.pallas import tpu as pltpu
-    # jax renamed TPUCompilerParams -> CompilerParams across versions
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
     kernel = functools.partial(
         _flash_kernel, bq=bq, bk=bk, causal=causal, window=window,
@@ -105,7 +103,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

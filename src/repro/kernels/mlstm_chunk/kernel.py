@@ -85,8 +85,6 @@ def mlstm_chunk_bhsd(q, k, v, log_i, log_f, *, chunk: int = 64,
     assert S % chunk == 0
     nc = S // chunk
     from jax.experimental.pallas import tpu as pltpu
-    # jax renamed TPUCompilerParams -> CompilerParams across versions
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
     kernel = functools.partial(_mlstm_kernel, chunk=chunk)
     spec4 = pl.BlockSpec((1, 1, chunk, d), lambda b_, h_, i: (b_, h_, i, 0))
@@ -102,7 +100,7 @@ def mlstm_chunk_bhsd(q, k, v, log_i, log_f, *, chunk: int = 64,
             pltpu.VMEM((d,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        compiler_params=params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, log_i, log_f)

@@ -72,8 +72,6 @@ def ssd_chunk_bhcp(x, a_dt, b, c, *, chunk: int = 128,
     assert S % chunk == 0
     nc = S // chunk
     from jax.experimental.pallas import tpu as pltpu
-    # jax renamed TPUCompilerParams -> CompilerParams across versions
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     return pl.pallas_call(
@@ -89,7 +87,7 @@ def ssd_chunk_bhcp(x, a_dt, b, c, *, chunk: int = 128,
                                lambda bb, h, i: (bb, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, a_dt, b, c)

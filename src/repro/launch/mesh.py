@@ -5,9 +5,9 @@ touches jax device state.  Single-pod: (16, 16) ("data", "model") = 256
 chips.  Multi-pod: (2, 16, 16) ("pod", "data", "model") = 512 chips; the
 ``pod`` axis is the Enoki replication domain (DCN), the inner axes are ICI.
 
-``jax.make_mesh`` grew the ``axis_types`` kwarg (and ``jax.sharding.AxisType``)
-only in newer jax releases; ``make_mesh_compat`` passes it when available so
-the same call sites work across versions.
+Meshes use ``AxisType.Auto`` axes (``jax.make_mesh`` defaults to
+``Explicit``): the sharding rules here annotate with ``NamedSharding`` and
+let the compiler propagate.
 """
 from __future__ import annotations
 
@@ -18,28 +18,19 @@ import jax
 from repro.configs.base import MULTI_POD_MESH, SINGLE_POD_MESH, MeshConfig
 
 
-def _axis_type_kwargs(num_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto, ...)`` where supported, ``{}`` otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
-def make_mesh_compat(shape: Sequence[int], axes: Sequence[str]):
-    """Version-tolerant ``jax.make_mesh`` (Auto axis types when supported)."""
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_from_config(cfg: MeshConfig):
-    return make_mesh_compat(cfg.shape, cfg.axes)
+    return make_mesh(cfg.shape, cfg.axes)
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
@@ -49,4 +40,4 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
     """Small mesh for CPU integration tests (requires
     XLA_FLAGS=--xla_force_host_platform_device_count>=prod(shape))."""
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)

@@ -301,8 +301,7 @@ def make_replicate_step(arch: ArchConfig, mesh: Mesh,
                     deq = qs.astype(jnp.float32) * ss.reshape(
                         (n_pods,) + (1,) * d.ndim)
                     return deq.mean(axis=0)
-                from repro.parallel.sharding import shard_map_compat
-                return shard_map_compat(
+                return jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(P(), P("pod")), out_specs=P(),
                     check_vma=False, axis_names={"pod"})(o, l)

@@ -296,8 +296,7 @@ def flash_decode_attention(q: jnp.ndarray, cache_k: jnp.ndarray,
         o_g = jax.lax.psum(o * corr[..., None], axis)
         return o_g / jnp.maximum(l_g, 1e-30)[..., None]
 
-    from repro.parallel.sharding import shard_map_compat
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None),
                   P()),

@@ -188,8 +188,7 @@ def moe_apply_ep(params: dict, x: jnp.ndarray, arch: ArchConfig, mesh,
             (yb * w_by_slot[:, None]).astype(xt_l.dtype), mode="drop")
         return jax.lax.psum(y, "model")
 
-    from repro.parallel.sharding import shard_map_compat
-    y = shard_map_compat(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", None), P("data", None), P("data", None),
                   P("model", None, None), P("model", None, None),

@@ -21,7 +21,6 @@ see launch/train.py.)
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional, Tuple
 
 import jax
@@ -36,36 +35,6 @@ COL_PARALLEL = {"wq", "wk", "wv", "w_gate", "w_up", "w_ff_gate", "w_ff_up",
                 "frame_proj"}
 ROW_PARALLEL = {"wo", "w_down", "w_out", "w_ff_down"}
 VOCAB_PARALLEL = {"embed"}
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, *,
-                     check_vma: bool = False, axis_names=None):
-    """Version-tolerant shard_map.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=, axis_names=)``; older
-    releases only have ``jax.experimental.shard_map.shard_map`` where the
-    replication check is spelled ``check_rep`` and everything runs
-    full-manual (no ``axis_names``; unsharded inputs are replicated per
-    device, which is what this repo's partial-manual call sites rely on).
-    Kwargs are selected by signature inspection so real TypeErrors from the
-    wrapped call surface unchanged."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        try:
-            params = inspect.signature(sm).parameters
-        except (TypeError, ValueError):
-            params = {}
-        if "check_vma" in params:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check_vma
-        if "axis_names" in params and axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return sm(f, **kwargs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
 
 
 def _axis_size(mesh: Mesh, name: str) -> int:

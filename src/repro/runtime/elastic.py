@@ -125,7 +125,9 @@ class ElasticMembership:
         with self._lock:
             nd = self.cluster.nodes[node]
             with nd.lock:
-                stores = dict(nd.stores)
+                # clones: the save reads them after the lock is gone, and
+                # the next fold donates the live arenas
+                stores = {kg: arena_clone(s) for kg, s in nd.stores.items()}
             mgr.save(step, stores, blocking=True)
         return True
 
@@ -265,8 +267,8 @@ class ElasticMembership:
                 src = c.nodes[live[0]]
                 with src.lock:
                     # clone, never share: replicas with aliased arenas
-                    # break under buffer donation (TPU/GPU folds
-                    # invalidate the donated input)
+                    # break under buffer donation (a fold invalidates the
+                    # donated input)
                     snapshot = arena_clone(src.stores[kg])
                 cnd = c.nodes[cand]
                 with cnd.lock:
@@ -463,8 +465,8 @@ def degraded_mesh_config(cfg: MeshConfig, alive_pods: int) -> MeshConfig:
 
 
 def make_mesh(cfg: MeshConfig) -> Mesh:
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat(cfg.shape, cfg.axes)
+    from repro.launch.mesh import make_mesh_from_config
+    return make_mesh_from_config(cfg)
 
 
 def remesh(state: Any, old_specs: Any, new_mesh: Mesh) -> Any:

@@ -1,27 +1,15 @@
 """Shared test plumbing.
 
-Three jobs:
+Two jobs:
 
-1. Register the ``slow`` marker so ``pytest.mark.slow`` doesn't warn.
-2. Guard the ``hypothesis`` dependency.  The property tests in
-   ``test_crdt_properties.py`` import hypothesis at module scope; without
-   this guard a missing install kills the *whole* ``pytest -x`` run at
-   collection.  When hypothesis is absent we install a tiny deterministic
-   shim (seeded draws, no shrinking) so the CRDT invariant tests still
-   execute as plain example-based tests.
-3. Arm lockdep (``repro.analysis.lockdep``) across the concurrency
+1. Register the ``slow`` and ``tier0`` markers.
+2. Arm lockdep (``repro.analysis.lockdep``) across the concurrency
    suites: every cluster/server built inside those tests gets ordered
    locks that assert the declared ``LOCK_ORDER`` at acquire time, and
    each test ends by verifying the accumulated cross-thread acquisition
    graph is violation- and cycle-free.
 """
 from __future__ import annotations
-
-import importlib.util
-import random
-import sys
-import types
-import zlib
 
 import pytest
 
@@ -67,96 +55,3 @@ def _lockdep_guard(request):
     finally:
         lockdep.disable()
     assert not problems, "lockdep:\n  " + "\n  ".join(problems)
-
-
-# ---------------------------------------------------------------------------
-# hypothesis fallback shim
-# ---------------------------------------------------------------------------
-
-class _Strategy:
-    """A draw function wrapper mirroring the tiny slice of the hypothesis
-    strategy API the CRDT tests use (including ``.map``)."""
-
-    def __init__(self, draw):
-        self._draw = draw
-
-    def map(self, fn):
-        return _Strategy(lambda rng: fn(self._draw(rng)))
-
-
-def _integers(min_value=0, max_value=100):
-    return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-
-def _floats(min_value=0.0, max_value=1.0, allow_nan=False, allow_infinity=False,
-            width=64):
-    return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-
-def _lists(elements, min_size=0, max_size=10):
-    def draw(rng):
-        n = rng.randint(min_size, max_size)
-        return [elements._draw(rng) for _ in range(n)]
-    return _Strategy(draw)
-
-
-def _tuples(*strategies):
-    return _Strategy(lambda rng: tuple(s._draw(rng) for s in strategies))
-
-
-def _sampled_from(seq):
-    items = list(seq)
-    return _Strategy(lambda rng: items[rng.randrange(len(items))])
-
-
-def _permutations(seq):
-    items = list(seq)
-    return _Strategy(lambda rng: rng.sample(items, len(items)))
-
-
-def _settings(max_examples=10, deadline=None, **_kw):
-    def deco(fn):
-        fn._shim_max_examples = max_examples
-        return fn
-    return deco
-
-
-def _given(*strategies):
-    def deco(fn):
-        n = min(getattr(fn, "_shim_max_examples", 10), 10)
-
-        def runner():
-            # deterministic per-test seed: same draws on every run
-            rng = random.Random(zlib.crc32(fn.__name__.encode()))
-            for _ in range(n):
-                fn(*[s._draw(rng) for s in strategies])
-
-        # NOT functools.wraps: pytest would introspect __wrapped__'s
-        # signature and treat the strategy parameters as fixtures
-        runner.__name__ = fn.__name__
-        runner.__doc__ = fn.__doc__
-        runner.__module__ = fn.__module__
-        return runner
-    return deco
-
-
-def _install_hypothesis_shim():
-    hyp = types.ModuleType("hypothesis")
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers = _integers
-    st.floats = _floats
-    st.lists = _lists
-    st.tuples = _tuples
-    st.sampled_from = _sampled_from
-    st.permutations = _permutations
-    hyp.given = _given
-    hyp.settings = _settings
-    hyp.strategies = st
-    hyp.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
-    hyp.__is_repro_shim__ = True
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st
-
-
-if importlib.util.find_spec("hypothesis") is None:
-    _install_hypothesis_shim()

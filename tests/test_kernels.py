@@ -149,10 +149,11 @@ def test_mlstm_chunk_matches_stepwise():
 # enoki_merge
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("R,V,tile", [(256, 128, 64), (512, 256, 256),
-                                      (64, 128, 64)])
+# one whole-arena tile; several tiles with the slot count padded to the
+# tile; a sub-lane-width payload padded to one 128-row group
+@pytest.mark.parametrize("R,V", [(256, 128), (3000, 256), (64, 8)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
-def test_enoki_merge_sweep(R, V, tile, dtype):
+def test_enoki_merge_sweep(R, V, dtype):
     from repro.kernels.enoki_merge.kernel import enoki_merge_rows
     from repro.kernels.enoki_merge.ref import enoki_merge_ref
     ks = jax.random.split(jax.random.PRNGKey(6), 4)
@@ -164,8 +165,7 @@ def test_enoki_merge_sweep(R, V, tile, dtype):
         b = jax.random.normal(ks[1], (R, V), dtype)
     aver = jax.random.randint(ks[2], (R,), 0, 50, jnp.int32)
     bver = jax.random.randint(ks[3], (R,), 0, 50, jnp.int32)
-    mv, mver = enoki_merge_rows(a, aver, b, bver, rows_tile=tile,
-                                interpret=True)
+    mv, mver = enoki_merge_rows(a, aver, b, bver, interpret=True)
     rv, rver = enoki_merge_ref(a, aver, b, bver)
     _allclose(mv, rv, 0, 0, "merge values")
     _allclose(mver, rver, 0, 0, "merge versions")
@@ -182,13 +182,31 @@ def test_enoki_merge_commutative_idempotent():
     # distinct versions => merge is commutative even on values
     aver = jax.random.permutation(ks[2], jnp.arange(R, dtype=jnp.int32))
     bver = jax.random.permutation(ks[3], jnp.arange(R, dtype=jnp.int32)) + R
-    ab = enoki_merge_rows(a, aver, b, bver, rows_tile=64, interpret=True)
-    ba = enoki_merge_rows(b, bver, a, aver, rows_tile=64, interpret=True)
+    ab = enoki_merge_rows(a, aver, b, bver, interpret=True)
+    ba = enoki_merge_rows(b, bver, a, aver, interpret=True)
     _allclose(ab[0], ba[0], 0, 0, "commutative values")
     _allclose(ab[1], ba[1], 0, 0, "commutative versions")
-    aa = enoki_merge_rows(ab[0], ab[1], ab[0], ab[1], rows_tile=64,
-                          interpret=True)
+    aa = enoki_merge_rows(ab[0], ab[1], ab[0], ab[1], interpret=True)
     _allclose(aa[0], ab[0], 0, 0, "idempotent")
+
+
+@pytest.mark.parametrize("width,dtype,ok", [
+    (2560, jnp.float32, True), (2561, jnp.float32, False),
+    (5120, jnp.bfloat16, True), (5121, jnp.bfloat16, False)])
+def test_merge_width_refused_when_declared(width, dtype, ok):
+    """A keygroup whose 1024-row merge tile would overrun the kernel's
+    VMEM ceiling is refused when it is declared, not at its first merge;
+    the widest admitted width stays under the ceiling."""
+    from repro.core.keygroup import KeygroupSpec
+    from repro.kernels.enoki_merge.kernel import (MAX_VMEM_BYTES,
+                                                  merge_vmem_bytes)
+    need = merge_vmem_bytes(4096, width, jnp.dtype(dtype).itemsize)
+    assert (need <= MAX_VMEM_BYTES) == ok
+    if ok:
+        KeygroupSpec(name="wide", value_width=width, dtype=dtype)
+    else:
+        with pytest.raises(ValueError, match="too wide"):
+            KeygroupSpec(name="wide", value_width=width, dtype=dtype)
 
 
 @pytest.mark.parametrize("n,row_width", [(10, 4), (8, 4), (3, 4), (7, 7)])

@@ -42,20 +42,18 @@ SCRIPT = textwrap.dedent("""
 
     # 2. pod-crossing classification: an all-reduce over ("pod",) crosses,
     # over ("model",) does not
-    from repro.parallel.sharding import shard_map_compat
-
     def pod_sum(x):
-        return shard_map_compat(lambda v: jax.lax.psum(v, "pod"), mesh=mesh,
-                                in_specs=P("pod"), out_specs=P(),
-                                check_vma=False, axis_names={"pod"})(x)
+        return jax.shard_map(lambda v: jax.lax.psum(v, "pod"), mesh=mesh,
+                             in_specs=P("pod"), out_specs=P(),
+                             check_vma=False, axis_names={"pod"})(x)
     t1 = jax.jit(pod_sum).lower(
         jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile().as_text()
     assert pod_crossing_bytes(t1, pod_size=4) > 0, "pod psum must cross"
 
     def model_sum(x):
-        return shard_map_compat(lambda v: jax.lax.psum(v, "model"), mesh=mesh,
-                                in_specs=P("model"), out_specs=P(),
-                                check_vma=False, axis_names={"model"})(x)
+        return jax.shard_map(lambda v: jax.lax.psum(v, "model"), mesh=mesh,
+                             in_specs=P("model"), out_specs=P(),
+                             check_vma=False, axis_names={"model"})(x)
     t2 = jax.jit(model_sum).lower(
         jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile().as_text()
     assert pod_crossing_bytes(t2, pod_size=4) == 0, "model psum is intra-pod"
