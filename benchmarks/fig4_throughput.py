@@ -577,13 +577,14 @@ def run_parallel_sweep(window_ms: float = PARALLEL_WINDOW_MS,
     def serve_pass(k):
         def run_once() -> int:
             cluster.engine = BatchedInvocationEngine(cluster)
-            errors = []
+            errors, lat = [], []
 
             def client(cid, srv):
                 fn = ("fig4_par_read", "fig4_par_write")[cid % 2]
                 try:
                     for _ in range(serve_n // serve_clients):
-                        srv.submit(fn, x).result(timeout=60.0)
+                        lat.append(srv.submit(fn, x).result(
+                            timeout=60.0).response_ms)
                 except BaseException as e:
                     errors.append(e)
 
@@ -597,7 +598,7 @@ def run_parallel_sweep(window_ms: float = PARALLEL_WINDOW_MS,
                 for t in threads:
                     t.join()
             assert not errors, errors[0]
-            serve_p99[k].append(percentiles(srv.response_ms)[99])
+            serve_p99[k].append(percentiles(lat)[99])
             cluster.engine.close()
             return srv.stats.served
         return run_once
@@ -645,21 +646,22 @@ def run_serving_sweep(n_requests: int = SERVE_REQUESTS,
         with FaasServer(cluster, window_ms=window_ms,
                         time_scale=time_scale) as srv:
             if mode == "open":
-                serve_open_loop(srv, "fig4_read", lambda i: x,
-                                n_requests=n_requests, rate_per_ms=1.0)
+                outs = serve_open_loop(srv, "fig4_read", lambda i: x,
+                                       n_requests=n_requests,
+                                       rate_per_ms=1.0)
             else:
-                serve_closed_loop(srv, "fig4_read", lambda i: x,
-                                  n_requests=n_requests, concurrency=4)
+                outs = serve_closed_loop(srv, "fig4_read", lambda i: x,
+                                         n_requests=n_requests,
+                                         concurrency=4)
             elapsed = time.perf_counter() - t0
-            pct = percentiles(srv.response_ms)
+            pct = percentiles([r.response_ms for r in outs])
             rows.append({"mode": mode, "window_ms": window_ms,
                          "requests": srv.stats.served,
                          "wall_ops_per_s": round(n_requests / elapsed, 1),
                          "p50_ms": round(pct[50], 2),
                          "p90_ms": round(pct[90], 2),
                          "p99_ms": round(pct[99], 2),
-                         "pumps": srv.stats.pumps,
-                         "wakeups": srv.stats.wakeups})
+                         "pumps": srv.stats.pumps})
     return rows
 
 

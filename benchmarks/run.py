@@ -64,16 +64,16 @@ def main_serve(argv):
                     time_scale=args.time_scale,
                     workers=args.workers) as srv:
         if args.mode == "closed":
-            serve_closed_loop(srv, "fig4_read", lambda i: x,
+            outs = serve_closed_loop(srv, "fig4_read", lambda i: x,
                               n_requests=args.requests,
                               concurrency=args.concurrency,
                               timeout_s=60.0)
         else:
-            serve_open_loop(srv, "fig4_read", lambda i: x,
-                            n_requests=args.requests,
-                            rate_per_ms=args.rate, timeout_s=60.0)
+            outs = serve_open_loop(srv, "fig4_read", lambda i: x,
+                                   n_requests=args.requests,
+                                   rate_per_ms=args.rate, timeout_s=60.0)
         elapsed = time.perf_counter() - t0
-        pct = percentiles(srv.response_ms)
+        pct = percentiles([r.response_ms for r in outs])
         rstats = srv.router.stats
         result = {"mode": args.mode, "requests": srv.stats.served,
                   "lost": srv.stats.lost,
@@ -88,7 +88,7 @@ def main_serve(argv):
                   "p99_ms": round(pct[99], 2),
                   "hedges_fired": rstats.hedges_fired,
                   "hedge_wins": rstats.hedge_wins,
-                  "pumps": srv.stats.pumps, "wakeups": srv.stats.wakeups,
+                  "pumps": srv.stats.pumps,
                   "repl_retries": cluster.stats.repl_retries,
                   "repl_dropped": cluster.stats.repl_dropped,
                   "repl_duped": cluster.stats.repl_duped,

@@ -68,7 +68,7 @@ def main():
                 for _ in range(128)]
         outs = [f.result(timeout=30.0) for f in futs]
     wall = time.perf_counter() - t0
-    pct = percentiles(srv.response_ms)
+    pct = percentiles([r.response_ms for r in outs])
     print(f"open loop: {len(outs)} requests in {wall*1e3:.0f} ms wall "
           f"({len(outs)/wall:.0f} ops/s), {srv.stats.pumps} pumps")
     print(f"  virtual latency p50/p99: {pct[50]:.1f}/{pct[99]:.1f} ms "
@@ -91,8 +91,8 @@ def main():
         with FaasServer(cluster, window_ms=16.0, time_scale=100.0,
                         hedge_after_ms=4.0 if hedged else None) as srv:
             futs = [srv.submit("wc_read", x) for _ in range(64)]
-            [f.result(timeout=30.0) for f in futs]
-        pct = percentiles(srv.response_ms)
+            outs = [f.result(timeout=30.0) for f in futs]
+        pct = percentiles([r.response_ms for r in outs])
         extra = (f", hedges fired/won: {srv.router.stats.hedges_fired}/"
                  f"{srv.router.stats.hedge_wins}" if hedged else "")
         print(f"straggler {'with' if hedged else 'no  '} hedge: "

@@ -46,13 +46,8 @@ class CompileCounter:
         return self
 
     def __exit__(self, *exc) -> bool:
-        try:
-            # jax exposes registration but not (yet) deregistration in the
-            # public monitoring API; fall back to leaving the listener in
-            # place (it only increments a dead counter) if the private
-            # helper moves
-            from jax._src import monitoring as _monitoring
-            _monitoring._unregister_event_listener_by_callback(self._cb)
-        except Exception:
-            pass
+        # jax's public monitoring API registers listeners but has no
+        # call to remove one
+        from jax._src import monitoring
+        monitoring.unregister_event_listener(self._cb)
         return False

@@ -90,6 +90,9 @@ LOCKS: Dict[str, Tuple[str, str]] = {
     "checkpoint.lock": (
         "CheckpointManager._lock",
         "writer-thread handoff"),
+    "spans.lock": (
+        "Recorder._lock",
+        "span record buffer and ticket -> dispatch map"),
 }
 
 #: Locks that never wrap another acquisition.  Anything may take a leaf;
@@ -104,6 +107,7 @@ LEAF_LOCKS: FrozenSet[str] = frozenset({
     "stats.lock",
     "naming.lock",
     "checkpoint.lock",
+    "spans.lock",
 })
 
 #: Direct outer -> inner edges (the transitive closure is what ``allowed``
@@ -186,6 +190,7 @@ CLASS_LOCK_ATTRS: Dict[str, str] = {
     "ElasticMembership": "membership.lock",
     "_NodePool": "engine.pool_lock",
     "CheckpointManager": "checkpoint.lock",
+    "Recorder": "spans.lock",
     "FaultPlane": "network.fault_lock",
     "HealthMonitor": "health.lock",
 }

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis import lockdep
+from repro.analysis import lockdep, spans
 from repro.configs.base import ReplicationPolicy
 from repro.core.engine import AtomicStats, BatchedInvocationEngine
 from repro.core.faas import (FunctionSpec, VectorCodec,
@@ -56,7 +56,7 @@ from repro.core.keygroup import KeygroupSpec, arena_new
 from repro.core.naming import NamingService
 from repro.core.network import FaultPlane, NetworkModel, paper_topology
 from repro.core.store import (Store, arena_clone, merge_snapshots_fused,
-                              store_assign_slots)
+                              padded_k, store_assign_slots)
 from repro.core.versioning import MAX_NODES
 
 
@@ -505,6 +505,9 @@ class Cluster:
                 heapq.heapify(keep)
                 q.heap = keep
             per_kg: Dict[str, List[Store]] = {}
+            # the seqs each merge folds, for its ``enoki.merge`` span
+            seqs: Optional[Dict[str, List[int]]] = (
+                {} if spans.on() else None)
             acks: List[Tuple[str, int]] = []
             dups = stale = 0
             for arrival, seq, kg, snapshot, source, epoch in sorted(
@@ -520,10 +523,17 @@ class Cluster:
                 if kg not in nd.stores:
                     continue    # replica crashed away mid-flight: stale
                 per_kg.setdefault(kg, []).append(snapshot)
+                if seqs is not None:
+                    seqs.setdefault(kg, []).append(seq)
             for kg, snaps in per_kg.items():
                 aligned = self._aligned.get(kg, False)
+                sp = (spans.Span(spans.MERGE, 0) if seqs is not None
+                      else None)
                 nd.stores[kg] = merge_snapshots_fused(
                     nd.stores[kg], snaps, aligned=aligned)
+                if sp is not None:
+                    sp.end(k=len(snaps), k_padded=padded_k(len(snaps)),
+                           seqs=tuple(seqs[kg]))
                 self.stats.inc("merge_dispatches")
                 self.stats.inc("merge_snapshots", len(snaps))
                 self.stats.inc("merge_aligned" if aligned
@@ -541,6 +551,7 @@ class Cluster:
         spec = self.policies[kg]
         if spec.policy != ReplicationPolicy.REPLICATED:
             return
+        sp = spans.start(spans.REPLICATE)
         with self.nodes[source].lock:
             # a queued snapshot must never alias the live arena: the
             # source's next fold and the target's fused merge DONATE their
@@ -571,6 +582,12 @@ class Cluster:
                 self._pump_entries(source, peer, entries, t0)
             with self._repl_lock:
                 self.replication_bytes += nbytes * len(targets)
+            if sp is not None:
+                # each link's newest entry is this snapshot's: entries
+                # leave an outbox only by an ack, under this lock
+                sp.end(bytes=nbytes, targets=len(targets),
+                       seqs=tuple(self._outboxes[(source, p)][-1].seq
+                                  for p in targets))
 
     def drop_pending_deliveries(self, node: str) -> int:
         """Discard every undelivered replication event addressed to

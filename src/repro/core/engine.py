@@ -111,7 +111,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis import lockdep
+from repro.analysis import lockdep, spans
 
 DEFAULT_BUCKETS = (1, 8, 64, 256)
 
@@ -173,6 +173,7 @@ class _Cycle:
     lock: threading.Lock = dataclasses.field(
         default_factory=lambda: lockdep.make_lock("engine.cycle_state_lock"),
         repr=False)
+    span: int = 0                   # the cycle's ``enoki.cycle`` span id
 
 
 @dataclasses.dataclass
@@ -726,43 +727,53 @@ class BatchedInvocationEngine:
         only place device dispatches happen.  ``deliver=False`` keeps all
         results in the return value (the synchronous ``dispatch`` path)."""
         with self._cycle_lock:
-            c = self.cluster
-            self.stats.inc("cycles")
-            cycle = _Cycle()
-            # ---- stage 1 (serial): shared deliver high-water mark — the
-            # latest arrival any group of this cycle brings to each store
-            # node (the cycle executes once its last member has arrived)
-            for w, floor in zip(windows, floors):
-                fn, node, _, _ = w.key
-                kg, store_node, _ = c._resolve_placement(c.specs[fn], node)
-                if kg is None:
-                    continue
-                hi = max(max(p.t_arrive for p in w.ps), floor or -math.inf)
-                cycle.hwm[store_node] = max(
-                    cycle.hwm.get(store_node, -math.inf), hi)
+            sp = spans.start(spans.CYCLE)
+            try:
+                c = self.cluster
+                self.stats.inc("cycles")
+                cycle = _Cycle(span=sp.id if sp is not None else 0)
+                # ---- stage 1 (serial): shared deliver high-water mark —
+                # the latest arrival any group of this cycle brings to
+                # each store node (the cycle executes once its last
+                # member has arrived)
+                for w, floor in zip(windows, floors):
+                    fn, node, _, _ = w.key
+                    kg, store_node, _ = c._resolve_placement(c.specs[fn],
+                                                             node)
+                    if kg is None:
+                        continue
+                    hi = max(max(p.t_arrive for p in w.ps),
+                             floor or -math.inf)
+                    cycle.hwm[store_node] = max(
+                        cycle.hwm.get(store_node, -math.inf), hi)
 
-            # ---- stage 2: the per-frame dataflow scheduler
-            run = _CycleRun(self, cycle, deliver)
-            out = run.run(windows, floors)
+                # ---- stage 2: the per-frame dataflow scheduler
+                run = _CycleRun(self, cycle, deliver)
+                out = run.run(windows, floors)
 
-            # ---- stage 3 (serial merge): ONE coalesced replication
-            # snapshot per written keygroup per node, with the post-cycle
-            # contents at the latest apply time.  Sorted for a
-            # deterministic event order regardless of which lane
-            # finished first
-            for (kg, store_node) in sorted(cycle.repl):
-                c._schedule_replication(kg, store_node,
-                                        cycle.repl[(kg, store_node)])
+                # ---- stage 3 (serial merge): ONE coalesced replication
+                # snapshot per written keygroup per node, with the
+                # post-cycle contents at the latest apply time.  Sorted
+                # for a deterministic event order regardless of which
+                # lane finished first
+                for (kg, store_node) in sorted(cycle.repl):
+                    c._schedule_replication(kg, store_node,
+                                            cycle.repl[(kg, store_node)])
 
-            if run.errors:
-                with self._qlock:
-                    self._ready.update(out)
-                # the lowest-seal-sequence failure: window errors in window
-                # order first, then the failing wave's earliest batch
-                err = min(run.errors, key=lambda e: e[0])[1]
-                self.errors.append(err)
-                raise err
-            return out
+                if run.errors:
+                    with self._qlock:
+                        self._ready.update(out)
+                    # the lowest-seal-sequence failure: window errors in
+                    # window order first, then the failing wave's earliest
+                    # batch
+                    err = min(run.errors, key=lambda e: e[0])[1]
+                    self.errors.append(err)
+                    raise err
+                return out
+            finally:
+                if sp is not None:
+                    sp.end(windows=len(windows),
+                           requests=sum(len(w.ps) for w in windows))
 
     def _finalize_ready(self, frames: List[_Frame]) -> bool:
         """Finalize every frame with no remaining work, cascading upward
@@ -809,10 +820,19 @@ class BatchedInvocationEngine:
         cap = self.buckets[-1]
         frames = []
         for lo in range(0, len(xs), cap):
-            frames.append(self._exec_chunk(
-                fn_name, node, xs[lo:lo + cap], t_sends[lo:lo + cap], client,
-                payload_bytes, floor, cycle, depth, parents[lo:lo + cap],
-                pendings[lo:lo + cap] if pendings is not None else None))
+            # one ``enoki.dispatch`` per chunk; a lane thread has no cycle
+            # span open, so the cycle is named
+            sp = spans.start(spans.DISPATCH, cycle.span)
+            try:
+                frames.append(self._exec_chunk(
+                    fn_name, node, xs[lo:lo + cap], t_sends[lo:lo + cap],
+                    client, payload_bytes, floor, cycle, depth,
+                    parents[lo:lo + cap],
+                    pendings[lo:lo + cap] if pendings is not None else None,
+                    sp))
+            finally:
+                if sp is not None:
+                    sp.end()
         return frames
 
     def _bucket(self, n: int) -> int:
@@ -920,9 +940,11 @@ class BatchedInvocationEngine:
     def _exec_chunk(self, fn_name: str, node: str, xs, t_sends, client: str,
                     payload_bytes: int, floor: Optional[float], cycle: _Cycle,
                     depth: int, parents,
-                    pendings: Optional[Sequence[_Pending]] = None) -> _Frame:
+                    pendings: Optional[Sequence[_Pending]] = None,
+                    span: Optional[spans.Span] = None) -> _Frame:
         """Run the main batched dispatch of one chunk (store effects +
-        per-request timeline); downstream routing is the cycle driver's job."""
+        per-request timeline); downstream routing is the cycle driver's job.
+        ``span`` is the chunk's open ``enoki.dispatch``, when tracing."""
         from repro.core.cluster import fires_sync_downstream
         from repro.core.keygroup import KeygroupSpec, arena_new
         from repro.core.versioning import MAX_NODES
@@ -983,9 +1005,20 @@ class BatchedInvocationEngine:
         # _stage_chunk/_valid_mask) so a warm chunk allocates nothing fresh
         # on the host
         bucket = self._bucket(n)
+        if span is not None:
+            span.set(fn=fn_name, node=node, n=n, bucket=bucket,
+                     kind="scan" if kg is not None and not bhandler.read_only
+                     else "map")
+            if pendings is not None:
+                spans.serve(self, [p.ticket for p in pendings], cycle.span,
+                            span.id)
+        sp = spans.start(spans.STAGE)
         xs_host = self._stage_chunk(xs, bucket)
         valid = _valid_mask(bucket, n)
+        if sp is not None:
+            sp.end()
 
+        sp = spans.start(spans.CALL)
         if kg is not None:
             # hold the STORE node's lock across read-dispatch-write so the
             # fold is atomic against any other toucher of this store
@@ -1006,6 +1039,8 @@ class BatchedInvocationEngine:
             new_store, new_clock, ys, ops = bhandler(
                 store, clock, jax.tree.map(jnp.asarray, xs_host),
                 valid, independent=True)
+        if sp is not None:
+            sp.end()
 
         # per-request timeline: identical charges to Cluster.invoke
         compute = nd.compute_ms.get(fn_name, 0.0)
@@ -1027,7 +1062,10 @@ class BatchedInvocationEngine:
                 self.stats.inc("replication_coalesced")
 
         # one transfer for the whole batch, then host-side row views
+        sp = spans.start(spans.FETCH)
         ys_host = jax.tree.map(np.asarray, jax.device_get(ys))
+        if sp is not None:
+            sp.end()
         outputs = [jax.tree.map(lambda a: a[i], ys_host) for i in range(n)]
         fires = ([fires_sync_downstream(y) for y in outputs]
                  if spec.calls else [True] * n)

@@ -285,13 +285,15 @@ def compile_batched_handler(spec: FunctionSpec, node_id: int,
             ns, nc, y = pure(s, c, x)
             return (store_select(v, ns, s), jnp.where(v, nc, c)), y
 
-        (fs, fc), ys = jax.lax.scan(step, (store, clock), (xs, valid))
+        with jax.named_scope("enoki.fold"):
+            (fs, fc), ys = jax.lax.scan(step, (store, clock), (xs, valid))
         return fs, fc, ys
 
     def mapped(store, clock, xs):
         # outputs only: the store result is dropped per-request, so vmap
         # never materialises a batched arena
-        return jax.vmap(lambda x: pure(store, clock, x)[2])(xs)
+        with jax.named_scope("enoki.fold"):
+            return jax.vmap(lambda x: pure(store, clock, x)[2])(xs)
 
     # donate the arena through the fold: XLA reuses the input buffers for
     # the output store, so warm folds stop allocating a fresh arena per

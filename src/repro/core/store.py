@@ -282,7 +282,10 @@ def arena_clone(store: Store) -> Store:
     Snapshot hygiene for donation: anything pushed into a delivery queue
     or shared across nodes must be a clone, never a live reference to an
     arena a later fold/merge may donate."""
-    return jax.tree.map(jnp.copy, store)
+    with jax.named_scope("enoki.clone"):
+        # XLA inserts the copies itself, with no op metadata; the barrier
+        # (free: the compiled program is the same) carries the scope
+        return jax.tree.map(jnp.copy, jax.lax.optimization_barrier(store))
 
 
 def merge_stores_aligned(a: Store, b: Store) -> Store:
@@ -326,9 +329,10 @@ def merge_many_fn(aligned: bool):
     body = merge_stores_aligned if aligned else merge_stores
 
     def many(acc: Store, snaps) -> Store:
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *snaps)
-        out, _ = jax.lax.scan(lambda s, snap: (body(s, snap), None),
-                              acc, stacked)
+        with jax.named_scope("enoki.merge"):
+            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *snaps)
+            out, _ = jax.lax.scan(lambda s, snap: (body(s, snap), None),
+                                  acc, stacked)
         return out
 
     return jax.jit(many, donate_argnums=(0,))
@@ -338,6 +342,12 @@ def merge_many_fn(aligned: bool):
 # pytree structure (a new K would retrace); beyond the largest bucket the
 # exact K runs — still one dispatch, just a fresh trace.
 SNAPSHOT_K_BUCKETS = (1, 2, 4, 8, 16, 32)
+
+
+def padded_k(k: int) -> int:
+    """K snapshots after padding to ``SNAPSHOT_K_BUCKETS`` (K itself
+    beyond the largest bucket)."""
+    return next((b for b in SNAPSHOT_K_BUCKETS if b >= k), k)
 
 
 def merge_snapshots_fused(acc: Store, snaps: Sequence[Store], *,
@@ -355,10 +365,7 @@ def merge_snapshots_fused(acc: Store, snaps: Sequence[Store], *,
     snaps = tuple(snaps)
     if not snaps:
         return acc
-    for k in SNAPSHOT_K_BUCKETS:
-        if k >= len(snaps):
-            snaps = snaps + (snaps[-1],) * (k - len(snaps))
-            break
+    snaps = snaps + (snaps[-1],) * (padded_k(len(snaps)) - len(snaps))
     return merge_many_fn(bool(aligned))(acc, snaps)
 
 

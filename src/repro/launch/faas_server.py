@@ -52,7 +52,7 @@ import time
 from concurrent import futures
 from typing import Any, Dict, List, Optional
 
-from repro.analysis import lockdep
+from repro.analysis import lockdep, spans
 from repro.core.cluster import Cluster, InvokeResult
 from repro.core.engine import AtomicStats
 from repro.core.router import Router
@@ -65,14 +65,17 @@ class RequestLost(RuntimeError):
 
 class ServedRequest(futures.Future):
     """Future for one submitted request (resolved by the serving loop):
-    a stdlib ``concurrent.futures.Future`` carrying the ticket and the
-    request's virtual send instant."""
+    a stdlib ``concurrent.futures.Future`` carrying the ticket, the
+    request's virtual send instant and, while a profiler session is
+    active, its ``enoki.request`` span."""
 
-    def __init__(self, ticket: int, fn: str, t_send: float):
+    def __init__(self, ticket: int, fn: str, t_send: float,
+                 span: Optional[spans.Request] = None):
         super().__init__()
         self.ticket = ticket
         self.fn = fn
         self.t_send = t_send            # virtual send instant (ms)
+        self.span = span
 
 
 @dataclasses.dataclass
@@ -81,9 +84,7 @@ class ServerStats(AtomicStats):
     served: int = 0
     lost: int = 0                   # futures failed (at-most-once drops)
     pumps: int = 0                  # pump passes that delivered results
-    wakeups: int = 0                # loop iterations (submits + deadlines)
     cycle_errors: int = 0           # exceptions a flush cycle raised
-    nodes_crashed: int = 0          # membership polls that took a node dark
 
 
 class FaasServer:
@@ -118,7 +119,6 @@ class FaasServer:
         self.health_poll_ms = health_poll_ms
         self.time_scale = time_scale
         self.stats = ServerStats()
-        self.response_ms: List[float] = []      # virtual latency per serve
         self.window_ms = window_ms
         self.max_batch = max_batch
         self.workers = workers
@@ -238,6 +238,17 @@ class FaasServer:
         (possibly earlier) new deadline.  Thread-safe, and the enqueue
         itself runs OUTSIDE the server lock — a submit never waits for a
         pump's dispatch in flight."""
+        sp = spans.start(spans.SUBMIT)
+        if sp is None:
+            return self._submit(fn_name, x, session_id, payload_bytes, None)
+        try:
+            return self._submit(fn_name, x, session_id, payload_bytes, sp)
+        finally:
+            sp.end()
+
+    def _submit(self, fn_name: str, x, session_id: Optional[str],
+                payload_bytes: int, sp: Optional[spans.Span]
+                ) -> ServedRequest:
         with self._cond:
             if not self._running:       # fail fast instead of enqueueing
                 # into a drained engine and hanging the future
@@ -263,7 +274,11 @@ class FaasServer:
                     self._fail_lost()
                     self._cond.notify_all()
             raise
-        fut = ServedRequest(ticket, fn_name, t_send)
+        req = None
+        if sp is not None:
+            sp.ticket = ticket
+            req = spans.Request(sp, ticket, self.cluster.engine)
+        fut = ServedRequest(ticket, fn_name, t_send, req)
         self.stats.inc("submitted")
         stopping = False
         with self._cond:
@@ -330,15 +345,15 @@ class FaasServer:
             with self._cond:
                 if not self._running:
                     return
-                self.stats.inc("wakeups")
                 gen0 = self._submit_gen
             if self.membership is not None:
                 # health plane first: a node that timed out crashes NOW,
                 # so this very turn's pump evicts its queued windows
                 # (reroute or fail-fast) instead of dispatching into it
-                crashed = self.membership.poll()
-                if crashed:
-                    self.stats.inc("nodes_crashed", len(crashed))
+                self.membership.poll()
+            sp = spans.start(spans.TURN)
+            if sp is not None:
+                served0 = self.stats.served
             # one pump TURN under the pump lock (fold -> deliver -> fail
             # lost stays atomic against the submit error path), OUTSIDE
             # the server lock: submits stay non-blocking while the engine
@@ -357,6 +372,8 @@ class FaasServer:
                 with self._cond:
                     self._deliver(results)
                     self._fail_lost()
+            if sp is not None:
+                sp.end(delivered=self.stats.served - served0)
             with self._cond:
                 if not self._running:
                     return
@@ -372,15 +389,19 @@ class FaasServer:
                        if self.membership is not None else None)
                 nxt = self.router.next_deadline()
                 if nxt is None:
-                    self._cond.wait(timeout=cap)    # submit, stop, or poll
-                    continue
-                delay = self._to_wall_s(nxt - self.now())
-                if cap is not None:
-                    delay = min(delay, cap)
-                if delay > 0:
+                    delay = cap                 # submit, stop, or poll
+                else:
                     # sleep EXACTLY until the next window close/hedge fire;
                     # a submit notifies and the loop re-arms
-                    self._cond.wait(timeout=delay)
+                    delay = self._to_wall_s(nxt - self.now())
+                    if cap is not None:
+                        delay = min(delay, cap)
+                    if delay <= 0:
+                        continue
+                sp = spans.start(spans.SLEEP)
+                self._cond.wait(timeout=delay)
+                if sp is not None:
+                    sp.end()
 
     def _on_engine_ready(self, results: Dict[int, InvokeResult]) -> None:
         """Mid-cycle delivery hook (``engine.on_ready``): called on the
@@ -403,9 +424,8 @@ class FaasServer:
         if not fut.set_running_or_notify_cancel():
             return                          # client gave up: drop quietly
         self.stats.inc("served")
-        # the router re-stamps hedge winners against the primary's
-        # send instant, so response_ms IS the client-observed latency
-        self.response_ms.append(res.response_ms)
+        if fut.span is not None:
+            fut.span.end(lost=False)
         fut.set_result(res)
 
     def _fail(self, fut: ServedRequest, why: str) -> None:
@@ -413,6 +433,8 @@ class FaasServer:
         if not fut.set_running_or_notify_cancel():
             return
         self.stats.inc("lost")
+        if fut.span is not None:
+            fut.span.end(lost=True)
         fut.set_exception(RequestLost(f"{why} ({fut.fn!r})"))
 
     def _deliver(self, results: Dict[int, InvokeResult]) -> None:
