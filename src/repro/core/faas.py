@@ -37,8 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.store import (Store, kv_delete, kv_get, kv_scan, kv_set,
-                              store_select)
+from repro.core.store import Store, kv_delete, kv_get, kv_scan, kv_set
 from repro.core.versioning import fnv1a
 
 
@@ -246,8 +245,9 @@ def compile_batched_handler(spec: FunctionSpec, node_id: int,
     Execution strategy, chosen from the handler's static op trace:
 
     * mutating handlers — a ``jax.lax.scan`` over the batch threads
-      (store, clock) through the requests in order, masking padded steps
-      with ``store_select``, so per-key last-writer-wins semantics and the
+      (store, clock) through the requests in order, each step's work
+      under a ``lax.cond`` on its valid flag (a padded step runs nothing
+      and outputs zeros), so per-key last-writer-wins semantics and the
       final clock are EXACTLY those of B sequential invocations — but the
       host pays one dispatch instead of B Python round-trips;
     * read-only handlers (only get/scan ops) — a ``jax.vmap`` over requests
@@ -279,11 +279,21 @@ def compile_batched_handler(spec: FunctionSpec, node_id: int,
     read_only = handler_read_only(op_log)
 
     def scanned(store, clock, xs, valid):
-        def step(carry, inp):
-            s, c = carry
-            x, v = inp
+        def run(s, c, x):
             ns, nc, y = pure(s, c, x)
-            return (store_select(v, ns, s), jnp.where(v, nc, c)), y
+            return (ns, nc), y
+
+        def skip(s, c, x):
+            y = jax.eval_shape(pure, s, c, x)[2]
+            return (s, c), jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype), y)
+
+        def step(carry, inp):
+            # a padded step skips the handler on the device (its probe,
+            # write and clock), so a bucket of 64 holding 17 requests
+            # does the work of 17; its output row reads zeros
+            x, v = inp
+            return jax.lax.cond(v, run, skip, *carry, x)
 
         with jax.named_scope("enoki.fold"):
             (fs, fc), ys = jax.lax.scan(step, (store, clock), (xs, valid))

@@ -65,8 +65,7 @@ def store_new(slots: int, value_width: int, num_nodes: int,
 def store_select(pred, a: Store, b: Store) -> Store:
     """``pred ? a : b`` over every arena leaf (pred: scalar bool, traced ok).
 
-    The workhorse of conditional writes (kv_set/kv_delete) and of masking
-    padded requests out of batched folds (see faas.compile_batched_handler).
+    The workhorse of conditional writes (kv_set/kv_delete).
     """
     pred = jnp.asarray(pred)
 

@@ -8,7 +8,10 @@ import pytest
 
 from repro.configs.base import ReplicationPolicy
 from repro.core import Cluster, enoki_function, get_function
-from repro.core.store import kv_set, kv_set_fold, store_contents, store_new
+from repro.core.faas import (KV, FunctionSpec, VectorCodec,
+                             compile_batched_handler, compile_handler)
+from repro.core.store import (kv_set, kv_set_fold, store_contents, store_new,
+                              store_select)
 from repro.core.versioning import MAX_NODES, fnv1a
 
 jax.config.update("jax_platform_name", "cpu")
@@ -364,3 +367,116 @@ def test_kv_set_fold_matches_sequential_sets():
     np.testing.assert_array_equal(
         np.asarray(contents[fnv1a("a")][2], np.float32),
         np.full((4,), 3.0, np.float32))
+
+
+def _fold_handler(kv, x):
+    """Read-modify-write of one key, a blind write of another, a scan of
+    both; returns values and the clock, so every output depends on the
+    state the step saw."""
+    cur, _ = kv.get("acc")
+    kv.set("acc", cur + x)
+    kv.set("last", x - cur)
+    tot, _ = kv.scan(["acc", "last"])
+    return jnp.concatenate([tot[:, 0], kv.state[1][None].astype(jnp.float32)])
+
+
+FOLD_SPEC = FunctionSpec(name="fold_skip", handler=_fold_handler,
+                         keygroups=["foldkg"], codec_width=8)
+FOLD_BUCKET = 64
+
+
+def _fold_state(node_id=3):
+    """A 2,048-slot arena holding both keys and some others, and a clock
+    already past zero."""
+    store, clock = store_new(2048, 8, MAX_NODES), jnp.int32(5)
+    for i, k in enumerate(("acc", "last", "k0", "k1")):
+        store, clock, _ = kv_set(store, fnv1a(k),
+                                 jnp.full((8,), i + 0.25, jnp.float32), 8,
+                                 clock, node_id)
+    return store, clock
+
+
+def _select_fold(store, clock, xs, valid, node_id=3):
+    """Reference fold: every step runs the handler, and a select over the
+    whole arena keeps its result only where the step is valid."""
+    codec = VectorCodec(FOLD_SPEC.codec_width)
+
+    def step(carry, inp):
+        (s, c), (x, v) = carry, inp
+        kv = KV(s, c, node_id, codec)
+        y = _fold_handler(kv, x)
+        ns, nc = kv.state
+        return (store_select(v, ns, s), jnp.where(v, nc, c)), y
+
+    (fs, fc), ys = jax.lax.scan(step, (store, clock), (xs, valid))
+    return fs, fc, ys
+
+
+FOLD_MASKS = {"n0": range(0), "n1": range(1), "n17": range(17),
+              "n64": range(64), "gaps_0_3_40": (0, 3, 40)}
+
+
+@pytest.mark.parametrize("mask", list(FOLD_MASKS))
+def test_fold_skips_padded_steps_exactly(mask):
+    """Whatever the mask, the fold that skips padded steps leaves the
+    store, the clock and every valid output bit-identical to the fold that
+    ran them and selected them away, and to one ``compile_handler`` call
+    per valid request in order: with none valid, to the arena and clock
+    it was given."""
+    node_id = 3
+    idx = list(FOLD_MASKS[mask])
+    valid = jnp.asarray(np.isin(np.arange(FOLD_BUCKET), idx))
+    xs = jnp.asarray(np.random.default_rng(7).standard_normal(
+        (FOLD_BUCKET, 8)).astype(np.float32))
+    store, clock = _fold_state(node_id)
+    bh = compile_batched_handler(FOLD_SPEC, node_id, xs[0])
+
+    ref_s, ref_c, ref_ys = jax.jit(_select_fold, static_argnums=4)(
+        store, clock, xs, valid, node_id)
+    got_s, got_c, got_ys, _ = bh(jax.tree.map(jnp.copy, store), clock, xs,
+                                 valid)
+    for leaf, want in zip(got_s, ref_s):
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got_c), np.asarray(ref_c))
+    np.testing.assert_array_equal(np.asarray(got_ys)[idx],
+                                  np.asarray(ref_ys)[idx])
+
+    step = compile_handler(FOLD_SPEC, node_id, xs[0])
+    s, c = store, clock
+    for i in idx:
+        s, c, y, _ = step(s, c, xs[i])
+        np.testing.assert_array_equal(np.asarray(got_ys)[i], np.asarray(y))
+    for leaf, want in zip(got_s, s):
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got_c), np.asarray(c))
+
+
+def test_fold_guards_each_step_inside_a_counted_donating_scan():
+    """``jit_scanned`` at bucket 64 keeps a scan with a known trip count,
+    puts the handler's work under a conditional inside it, and still
+    consumes the arena it is given."""
+    store, clock = _fold_state()
+    xs = jnp.zeros((FOLD_BUCKET, 8), jnp.float32)
+    valid = jnp.arange(FOLD_BUCKET) < 17
+    bh = compile_batched_handler(FOLD_SPEC, 3, xs[0])
+
+    outer = jax.make_jaxpr(bh.jit_scan)(store, clock, xs, valid).jaxpr
+    (scan,) = [e for e in outer.eqns[0].params["jaxpr"].eqns
+               if e.primitive.name == "scan"]
+    assert scan.params["length"] == FOLD_BUCKET
+    body = scan.params["jaxpr"].jaxpr
+    (cond,) = [e for e in body.eqns if e.primitive.name == "cond"]
+    writes = {"scatter", "dynamic_update_slice"}
+    # the store is written only inside the guarded branch, never beside it
+    assert not writes & {e.primitive.name for e in body.eqns}
+    skip, run = cond.params["branches"]
+    assert writes & {e.primitive.name for e in run.jaxpr.eqns}
+    assert not writes & {e.primitive.name for e in skip.jaxpr.eqns}
+
+    hlo = bh.jit_scan.lower(store, clock, xs, valid).compile().as_text()
+    assert hlo.startswith("HloModule jit_scanned")
+    assert '"known_trip_count":{"n":"64"}' in hlo
+    assert " conditional(" in hlo
+
+    bh(store, clock, xs, valid)
+    assert store.values.is_deleted() and store.keys.is_deleted()
