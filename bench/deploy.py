@@ -3,20 +3,24 @@ cluster, its keygroup, its functions, the records filled from the seed,
 and the warm-up of exactly the shapes a cell's traffic reaches.
 
 A configuration file (``bench/configs/<name>.json``) names its nodes, the
-keygroup's replicas and the functions by kind; the kinds are below.
+keygroup's replicas and its functions, each by kind: the kind
+``bench/kinds/<kind>.py`` deploys them, says which requests of a schedule
+they serve, builds each such request's input and lists the engine buckets
+they reach (module docstring of ``bench/kinds/scan_read.py``).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List
+import pathlib
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference
-from repro.core import Cluster, enoki_function, get_function
+from bench import reference, spec
+from repro.core import Cluster, get_function
 from repro.core.engine import _valid_mask
 from repro.core.keygroup import KeygroupSpec
 from repro.core.network import paper_topology
@@ -30,24 +34,10 @@ def key_names(n: int) -> List[str]:
     return [f"user{i}" for i in range(n)]
 
 
-def scan_read(name: str, kg: str, keys: List[str], width: int):
-    """Read one of the request keys: the handler scans all of them and
-    returns the row that the request's index names."""
-    def handler(kv, x):
-        vals, _ = kv.scan(keys)
-        return vals[jnp.clip(x[0].astype(jnp.int32), 0, len(keys) - 1)]
-    return enoki_function(name=name, keygroups=[kg],
-                          codec_width=width)(handler)
-
-
-def blind_write(name: str, kg: str, key: str, width: int):
-    """Write the whole record of one literal key; returns the Lamport
-    clock the write took."""
-    def handler(kv, x):
-        kv.set(key, x)
-        return kv.state[1]
-    return enoki_function(name=name, keygroups=[kg],
-                          codec_width=width)(handler)
+def keys_are_records(config: dict) -> bool:
+    """Whether the configuration's request keys are all of its records
+    (else they are ``requestkeys`` named keys among them)."""
+    return int(config["requestkeys"]) == int(config["recordcount"])
 
 
 @dataclasses.dataclass
@@ -56,52 +46,51 @@ class Deployment:
     kg: str
     replicas: List[str]
     client: str
-    read_fn: str
-    update_fns: List[str]       # one per request key
-    fn_nodes: Dict[str, List[str]]
+    keys: int                   # request keys
+    named_keys: bool            # False: request key i is record i
     width: int
     dtype: object
-    examples: Dict[str, np.ndarray]
+    # the configuration's function entries, each with its kind's module
+    functions: List[Tuple[object, dict]] = dataclasses.field(
+        default_factory=list)
+    fn_nodes: Dict[str, List[str]] = dataclasses.field(default_factory=dict)
+    examples: Dict[str, np.ndarray] = dataclasses.field(default_factory=dict)
+    # request key i's hash in the arena, set by ``fill``
+    key_hashes: Optional[np.ndarray] = None
+
+    def place(self, name: str, nodes: List[str], example) -> None:
+        """Deploy the registered function ``name`` on ``nodes``."""
+        self.cluster.deploy(get_function(name), list(nodes),
+                            example_input=jax.tree.map(jnp.asarray, example))
+        self.fn_nodes[name] = list(nodes)
+        self.examples[name] = example
 
 
-def deploy(config: dict, slots: int, dtype=None) -> Deployment:
+def deploy(config: dict, slots: int, dtype=None,
+           root: pathlib.Path = spec.ROOT) -> Deployment:
     """The configuration's cluster with its functions deployed.  ``slots``
     is the record count held per replica; ``dtype`` overrides the record
     type (the control runs the keygroup one precision lower)."""
-    width = int(config["record_floats"])
-    dtype = jnp.dtype(dtype or config["dtype"])
     kgc = config["keygroup"]
-    kg = kgc["name"]
-    keys = key_names(int(config["requestkeys"]))
-    cluster = Cluster(dict(config["nodes"]),
-                      net=TOPOLOGIES[config["topology"]](),
-                      measure_compute=False)
-    cluster.create_keygroup(KeygroupSpec(name=kg, slots=slots,
-                                         value_width=width, dtype=dtype),
-                            list(kgc["replicas"]))
-    read_fn, update_fns, fn_nodes, examples = None, [], {}, {}
-    for f in config["functions"]:
-        if f["kind"] == "scan_read":
-            scan_read(f["name"], kg, keys, width)
-            names, ex = [f["name"]], np.zeros((1,), np.float32)
-            read_fn = f["name"]
-        elif f["kind"] == "blind_write":
-            names = [f"{f['name']}_{i}" for i in range(len(keys))]
-            for name, key in zip(names, keys):
-                blind_write(name, kg, key, width)
-            ex = np.zeros((width,), np.float32)
-            update_fns = names
-        else:
-            raise ValueError(f"unknown function kind {f['kind']!r}")
-        for name in names:
-            cluster.deploy(get_function(name), list(f["nodes"]),
-                           example_input=jnp.asarray(ex))
-            fn_nodes[name] = list(f["nodes"])
-            examples[name] = ex
-    return Deployment(cluster=cluster, kg=kg, replicas=list(kgc["replicas"]),
-                      client=config["client"], read_fn=read_fn,
-                      update_fns=update_fns, fn_nodes=fn_nodes, width=width,
-                      dtype=dtype, examples=examples)
+    dep = Deployment(
+        cluster=Cluster(dict(config["nodes"]),
+                        net=TOPOLOGIES[config["topology"]](),
+                        measure_compute=False),
+        kg=kgc["name"], replicas=list(kgc["replicas"]),
+        client=config["client"],
+        keys=slots if keys_are_records(config) else int(
+            config["requestkeys"]),
+        named_keys=not keys_are_records(config),
+        width=int(config["record_floats"]),
+        dtype=jnp.dtype(dtype or config["dtype"]))
+    dep.cluster.create_keygroup(
+        KeygroupSpec(name=dep.kg, slots=slots, value_width=dep.width,
+                     dtype=dep.dtype), dep.replicas)
+    for entry in config["functions"]:
+        kind = spec.load_part("kinds", entry["kind"], root)
+        kind.deploy(dep, entry)
+        dep.functions.append((kind, entry))
+    return dep
 
 
 @functools.partial(jax.jit, static_argnames=("slots", "width", "dtype"))
@@ -110,31 +99,45 @@ def fill_values(key, slots: int, width: int, dtype):
     return jax.random.normal(key, (slots, width), jnp.float32).astype(dtype)
 
 
-def fill_layout(seed: int, slots: int, hot_keys: int):
+def fill_layout(seed: int, slots: int, hot_keys: int,
+                named: bool = True):
     """(keys, hot_slots): distinct non-zero key hashes for every slot, the
-    request keys' own hashes at seeded slots."""
+    request keys' at ``hot_slots``.  Named request keys (``user<i>``) take
+    their own hashes at seeded slots; where the request keys are all the
+    records (``named`` false), request key i is record i with the fill's
+    own hash, and no name is hashed."""
+    if not named:
+        return _fresh_hashes(seed, slots).astype(np.int32), np.arange(slots)
     rng = np.random.default_rng([seed, 2])
     hot = np.array([reference.fnv1a(k) for k in key_names(hot_keys)],
                    np.int64)
-    fresh = (np.arange(slots + hot.size, dtype=np.int64) * 2654435761
-             + seed % _PRIME) % _PRIME + 1
+    fresh = _fresh_hashes(seed, slots + hot.size)
     fresh = fresh[~np.isin(fresh, hot)][:slots]
     hot_slots = rng.choice(slots, hot.size, replace=False)
     fresh[hot_slots] = hot
     return fresh.astype(np.int32), hot_slots
 
 
+def _fresh_hashes(seed: int, n: int) -> np.ndarray:
+    """``n`` distinct hashes in [1, 2**31 - 1), from the seed."""
+    return (np.arange(n, dtype=np.int64) * 2654435761
+            + seed % _PRIME) % _PRIME + 1
+
+
 def jax_seed(seed: int) -> int:
     return int(np.random.default_rng([seed, 3]).integers(2**31 - 1))
 
 
-def fill(dep: Deployment, seed: int, writer: str, hot_keys: int):
+def fill(dep: Deployment, seed: int, writer: str):
     """Load every replica with the same seeded records, stamped as written
-    by ``writer`` at Lamport clock 1.  Returns (keys, hot_slots)."""
+    by ``writer`` at Lamport clock 1, and note each request key's hash in
+    ``dep.key_hashes``.  Returns (keys, hot_slots)."""
     nodes = dep.cluster.nodes
     with nodes[dep.replicas[0]].lock:
         slots = int(nodes[dep.replicas[0]].stores[dep.kg].keys.shape[0])
-    keys, hot_slots = fill_layout(seed, slots, hot_keys)
+    keys, hot_slots = fill_layout(seed, slots, dep.keys,
+                                  named=dep.named_keys)
+    dep.key_hashes = keys[hot_slots]
     writer_id = nodes[writer].node_id
     vv = np.zeros(reference.NODES_PACKED, np.int32)
     vv[writer_id] = 1

@@ -2,19 +2,19 @@
 and records, on the host clock, when each request was due, sent and
 answered.
 
-The open loop sends each request at its due instant whatever the server
-does, from one thread.  A request's answer counts when its output is on
-the host: the future's completion callback stamps it.
+A client loop (``bench/loops/<loop>.py``) decides when each request is
+sent.  A request's answer counts when its output is on the host: the
+future's completion callback stamps it.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List
+from typing import Callable, List, Optional
 
 import numpy as np
 
-from bench.traffic import READ, Schedule
+from bench.traffic import Schedule
 
 WAIT_AFTER_S = 60.0             # how long past the window answers may come
 
@@ -49,34 +49,56 @@ class History:
         self.done_ns[i] = now
 
 
-def request(dep, sched: Schedule, i: int):
-    """(function, input) of request ``i``."""
-    k = int(sched.key[i])
-    if sched.kind[i] == READ:
-        return dep.read_fn, np.float32([k])
-    return dep.update_fns[k], sched.row(int(sched.update_id[i]))
+def route(dep, sched: Schedule) -> np.ndarray:
+    """Per schedule entry, the index in ``dep.functions`` of the kind that
+    serves it; an entry that no kind or two kinds serve is an error."""
+    owner = np.full(len(sched), -1, np.int64)
+    for j, (kind, entry) in enumerate(dep.functions):
+        mine = np.asarray(kind.serves(dep, entry, sched.kind, sched.key))
+        if np.any(owner[mine] >= 0):
+            raise ValueError(f"function {entry['name']!r} serves requests "
+                             f"another function serves")
+        owner[mine] = j
+    if np.any(owner < 0):
+        i = int(np.argmax(owner < 0))
+        raise ValueError(f"no function serves request {i} (kind "
+                         f"{int(sched.kind[i])}, key {int(sched.key[i])})")
+    return owner
 
 
-def _send(srv, dep, sched: Schedule, hist: History, i: int):
-    fn, x = request(dep, sched, i)
-    hist.send_ns[i] = time.perf_counter_ns()
-    fut = srv.submit(fn, x)
-    hist.ticket[i] = fut.ticket
-    hist.issued = max(hist.issued, i + 1)
-    fut.add_done_callback(lambda f, i=i: hist.answer(i, f))
-    return fut
+class Client:
+    """Sends entries of a schedule to the server and records them in its
+    ``hist``; a client loop (``bench/loops/<loop>.py``) decides when."""
 
+    def __init__(self, srv, dep, sched: Schedule):
+        self.srv, self.dep, self.sched = srv, dep, sched
+        self.owner = route(dep, sched)
+        self.hist = History.empty(len(sched))
 
-def open_loop(srv, dep, sched: Schedule, t0_ns: int) -> History:
-    """Send every request at ``t0_ns + due``; return once all are sent."""
-    hist = History.empty(len(sched))
-    hist.due_ns[:] = t0_ns + sched.due_ns
-    for i in range(len(sched)):
-        delay = hist.due_ns[i] - time.perf_counter_ns()
-        if delay > 0:
-            time.sleep(delay / 1e9)
-        _send(srv, dep, sched, hist, i)
-    return hist
+    def request(self, i: int):
+        """(function, input) of request ``i``."""
+        kind, entry = self.dep.functions[self.owner[i]]
+        return kind.request(self.dep, entry, self.sched, i)
+
+    def send(self, i: int, due_ns: Optional[int] = None,
+             on_done: Optional[Callable[[int], None]] = None):
+        """Submit request ``i`` (due at ``due_ns`` when given); once its
+        answer is stamped, ``on_done(i)``."""
+        hist = self.hist
+        fn, x = self.request(i)
+        if due_ns is not None:
+            hist.due_ns[i] = due_ns
+        hist.send_ns[i] = time.perf_counter_ns()
+        fut = self.srv.submit(fn, x)
+        hist.ticket[i] = fut.ticket
+        hist.issued = max(hist.issued, i + 1)
+
+        def answered(f, i=i):
+            hist.answer(i, f)
+            if on_done is not None:
+                on_done(i)
+        fut.add_done_callback(answered)
+        return fut
 
 
 def wait_answers(hist: History, t1_ns: int,

@@ -5,7 +5,8 @@ it builds the cell's configuration on the system under test, fills the
 records from the seed, warms the shapes the cell's traffic reaches, serves
 the traffic for ``seconds`` through ``FaasServer`` (tracing the window when
 asked), waits for every answer, drains replication, and holds what was
-served and both arenas to the plain reference.  The result is the line
+served and both arenas to the plain reference that the configuration
+names (``reference``, a path in the checkout).  The result is the line
 ``run.py`` prints.
 """
 from __future__ import annotations
@@ -23,7 +24,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from bench import deploy, drive, reference, traffic
+from bench import deploy, drive, spec, traffic
 from bench import trace as tracing
 from bench.spec import Cell
 from repro.analysis.jitprof import CompileCounter
@@ -40,17 +41,12 @@ def needed_buckets(cell: Cell, dep: deploy.Deployment,
                    sched: traffic.Schedule,
                    engine_buckets) -> Dict[str, List[int]]:
     """The engine buckets each function of the cell can reach in the
-    window: every bucket, for each function the schedule calls.  A host
-    that stalls for a moment lets any number of requests gather, and the
-    engine cuts a batch at its largest bucket."""
+    window, as the functions' kinds list them."""
+    owner = drive.route(dep, sched)
     fns = {}
-    for i, fn in enumerate([dep.read_fn] + dep.update_fns):
-        kind = traffic.READ if i == 0 else traffic.UPDATE
-        mine = sched.kind == kind
-        if kind == traffic.UPDATE:
-            mine &= sched.key == i - 1
-        if mine.any():
-            fns[fn] = list(engine_buckets)
+    for j, (kind, entry) in enumerate(dep.functions):
+        fns.update(kind.buckets(dep, entry, sched, owner == j,
+                                engine_buckets))
     return fns
 
 
@@ -140,13 +136,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     tests and the control use them; the benchmark's runs never do)."""
     cfg = cell.config
     slots = int(slots or cfg["recordcount"])
-    keys = int(cfg["requestkeys"])
+    ref = spec.load_module(cell.root / cfg["reference"], "the reference")
+    loop = spec.load_part("loops", cell.traffic["loop"], cell.root)
     with _Events() as setup_events:
-        dep = deploy.deploy(cfg, slots, dtype)
+        dep = deploy.deploy(cfg, slots, dtype, cell.root)
         writer = cfg["keygroup"]["fill_node"]
-        layout = deploy.fill(dep, seed, writer, keys)
-        sched = traffic.make_schedule(cell.traffic, keys, dep.width, seed,
-                                      seconds, cell.params["rate_per_s"])
+        layout = deploy.fill(dep, seed, writer)
+        sched = traffic.make_schedule(cell.traffic, dep.keys, dep.width,
+                                      seed, seconds,
+                                      cell.params["rate_per_s"], cell.root)
         eng = dep.cluster.engine
         buckets = needed_buckets(cell, dep, sched, eng.buckets)
         warmed = deploy.warm(dep, buckets)
@@ -158,6 +156,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                          time_scale=float(scfg["time_scale"]),
                          workers=scfg["workers"])
         srv.start()
+        client = drive.Client(srv, dep, sched)
     setup_s = time.perf_counter() - t_start
     hits = setup_events.counts["/jax/compilation_cache/cache_hits"]
     misses = setup_events.counts["/jax/compilation_cache/cache_misses"]
@@ -172,10 +171,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         t1 = t0 + int(seconds * 1e9)
         with _traced(trace, log_dir), \
                 jax.profiler.TraceAnnotation(tracing.WINDOW_ANNOTATION):
-            hist = drive.open_loop(srv, dep, sched, t0)
+            try:
+                loop.drive(client, t0, t1, cell.traffic)
+            except BaseException:
+                srv.stop(drain=False)
+                raise
             _sleep_until(t1)
         in_window = compiles.events
     log(f"compiles inside the window: {in_window} {compiled.names}")
+    hist = client.hist
     drive.wait_answers(hist, t1)
     srv.stop()
     dep.cluster.flush_replication()
@@ -197,28 +201,28 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     for node in dep.replicas:
         nd = dep.cluster.nodes[node]
         with nd.lock:
-            arenas[node] = dict(zip(reference.LEAVES,
+            arenas[node] = dict(zip(ref.LEAVES,
                                     jax.device_get(nd.stores[dep.kg])))
     writer_id = dep.cluster.nodes[writer].node_id
-    update_writer = dep.cluster.nodes[dep.fn_nodes[dep.update_fns[0]][0]]\
-        .node_id if dep.update_fns else writer_id
+    update_writer = _update_writer(dep, client, sched)
+    if update_writer is None:
+        update_writer = writer_id
     arena_info = {"slots": slots, "width": dep.width,
                   "itemsize": int(np.dtype(dep.dtype).itemsize)}
-    del dep, srv, eng
+    del dep, srv, eng, client
     gc.collect()
-    fill = reference.Fill(
+    fill = ref.Fill(
         keys=layout[0], hot_slots=layout[1], writer_id=writer_id,
         values=np.asarray(jax.device_get(deploy.fill_values(
             jax.random.key(deploy.jax_seed(seed)), slots,
             arena_info["width"], np.dtype(cfg["dtype"])))))
     n = hist.issued
-    obs = reference.Observed(
+    obs = ref.Observed(
         kind=sched.kind[:n], key=sched.key[:n],
         update_id=sched.update_id[:n], ticket=hist.ticket[:n],
         send_ns=hist.send_ns[:n], done_ns=hist.done_ns[:n],
         failed=hist.failed[:n], outputs=hist.outputs[:n])
-    checks, clocks = reference.check(obs, fill, sched.row, update_writer,
-                                     arenas)
+    checks, clocks = ref.check(obs, fill, sched.row, update_writer, arenas)
     del arenas
 
     summary = None
@@ -253,6 +257,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     result["checks"] = {k: {"value": v, "limit": LIMITS}
                         for k, v in checks.items()}
     return result
+
+
+def _update_writer(dep, client, sched) -> Optional[int]:
+    """Node id of the function that serves the schedule's first update
+    (None where the schedule updates nothing)."""
+    upd = np.flatnonzero(sched.kind == traffic.UPDATE)
+    if upd.size == 0:
+        return None
+    fn, _ = client.request(int(upd[0]))
+    return dep.cluster.nodes[dep.fn_nodes[fn][0]].node_id
 
 
 def _counters(dep, srv) -> Dict[str, float]:

@@ -1,7 +1,7 @@
 """Share of the fold's scan steps that are bucket padding: the sum over
 the window's ``enoki.dispatch`` spans of kind ``scan`` of (bucket - n)
-over the sum of bucket, in percent.  Each padded step still selects the
-whole arena."""
+over the sum of bucket, in percent.  A padded step is still a step of the
+scan, but the device skips its work (a branch on its valid flag)."""
 from bench import spanread
 
 

@@ -5,8 +5,11 @@ traffic mix (``bench/traffic/<traffic>.json``), and has a cell file of its
 own (``bench/cells/<workload>.json``, its offered rate).  Every metric, end
 to end or per layer, is read by ``bench/metrics/<metric>.py``; which cells
 report it, its unit and what it moves are said in ``BENCHMARK.json`` alone.
-Adding any of them is adding files and entries; no file here names a
-cell.
+A configuration's functions are deployed by ``bench/kinds/<kind>.py``, and
+it names its plain reference by path; a mix's key distribution is
+``bench/dists/<requestdistribution>.py`` (``uniform`` where the mix names
+none) and its client loop ``bench/loops/<loop>.py``.  Adding any of them
+is adding files and entries; no file here names a cell.
 """
 from __future__ import annotations
 
@@ -14,6 +17,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import sys
 from typing import Dict, List, Optional
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -60,20 +64,36 @@ class Metric:
     reader: object                  # module with read(run)
 
 
-def load_reader(name: str, root: pathlib.Path = ROOT):
-    path = pathlib.Path(root) / "bench" / "metrics" / f"{name}.py"
+def load_module(path: pathlib.Path, what: str):
+    """The Python file at ``path``, executed afresh as a module of its
+    own (listed in ``sys.modules``, which dataclasses look up)."""
+    path = pathlib.Path(path)
     if not path.is_file():
-        raise SpecError(f"no reader {path} for metric {name!r}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "__"), path)
+        raise SpecError(f"no {path} for {what}")
+    name = "bench_" + "_".join(path.with_suffix("").parts[-2:]).replace(
+        ".", "__")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_part(group: str, name: str, root: pathlib.Path = ROOT):
+    """``bench/<group>/<name>.py``: a metric's reader, a function kind, a
+    key distribution or a client loop, found by its name."""
+    return load_module(pathlib.Path(root) / "bench" / group / f"{name}.py",
+                       f"{group[:-1]} {name!r}")
+
+
+def load_reader(name: str, root: pathlib.Path = ROOT):
+    return load_part("metrics", name, root)
 
 
 @dataclasses.dataclass
 class Cell:
     name: str
+    root: pathlib.Path              # the checkout its files were found in
     chips: int
     config: dict
     traffic: dict
@@ -108,7 +128,7 @@ def load_cell(root: pathlib.Path, name: str) -> Cell:
                        reader=load_reader(m["name"], root))
                 for m in entries if metric_applies(m, name, e2e)]
 
-    return Cell(name=name, chips=int(w["chips"]), config=config,
+    return Cell(name=name, root=root, chips=int(w["chips"]), config=config,
                 traffic=traffic, params=params,
                 end_to_end=metrics(bench["end_to_end"], False),
                 per_layer=metrics(bench["per_layer"], True))

@@ -63,10 +63,15 @@ def main(argv=None) -> int:
 
     from bench import deploy, drive, harness, traffic
     from repro.launch.faas_server import FaasServer
+    if cell.traffic["loop"] != "open":
+        print("sweep.py: the cell's mix is not an open loop",
+              file=sys.stderr)
+        return 2
+    open_loop = spec.load_part("loops", "open")
     cfg = cell.config
-    keys = int(cfg["requestkeys"])
     dep = deploy.deploy(cfg, int(cfg["recordcount"]))
-    deploy.fill(dep, args.seed, cfg["keygroup"]["fill_node"], keys)
+    deploy.fill(dep, args.seed, cfg["keygroup"]["fill_node"])
+    keys = dep.keys
     top = traffic.make_schedule(cell.traffic, keys, dep.width, args.seed,
                                 args.seconds, max(args.rates))
     warmed = deploy.warm(dep, harness.needed_buckets(
@@ -84,9 +89,11 @@ def main(argv=None) -> int:
                         client=dep.client,
                         time_scale=float(scfg["time_scale"]),
                         workers=scfg["workers"]) as srv:
+            client = drive.Client(srv, dep, sched)
             t0 = time.perf_counter_ns() + 20_000_000
             t1 = t0 + int(args.seconds * 1e9)
-            hist = drive.open_loop(srv, dep, sched, t0)
+            open_loop.drive(client, t0, t1, cell.traffic)
+            hist = client.hist
             harness._sleep_until(t1)
             # requests due before the window's last second, answered in it
             early = hist.due_ns < t1 - 1_000_000_000

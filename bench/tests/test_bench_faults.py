@@ -27,3 +27,26 @@ def test_replication_left_out_is_caught(monkeypatch, tmp_path):
     assert res["correct"] is False
     assert res["checks"]["arena_diff.edge2"]["value"] > 0
     assert res["checks"]["arena_diff.edge"]["value"] == 0
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half", "alter"])
+def test_broken_fold_is_caught_in_the_closed_loop(fault, monkeypatch,
+                                                  tmp_path):
+    bench_cpu.broken_fold(monkeypatch, **{fault: True})
+    res = bench_cpu.run(bench_cpu.CLOSED, seed=31, tmp_path=tmp_path,
+                        rate=2000.0, clients=4,
+                        root=bench_cpu.closed_cell_root(tmp_path))
+    assert res["correct"] is False, res["checks"]
+
+
+def test_replication_left_out_is_caught_in_the_closed_loop(monkeypatch,
+                                                           tmp_path):
+    from repro.core.cluster import Cluster
+    monkeypatch.setattr(Cluster, "_schedule_replication",
+                        lambda self, kg, source, t_apply: None)
+    res = bench_cpu.run(bench_cpu.CLOSED, seed=37, tmp_path=tmp_path,
+                        rate=2000.0, clients=4,
+                        root=bench_cpu.closed_cell_root(tmp_path))
+    assert res["correct"] is False
+    assert res["checks"]["arena_diff.edge2"]["value"] > 0
+    assert res["checks"]["arena_diff.edge"]["value"] == 0

@@ -72,8 +72,21 @@ def test_update_rows_are_distinct_and_numbered():
 
 
 def test_the_cells_mix_and_other_loops_are_refused():
+    """A closed loop is a loop file like the open one; a loop or a key
+    distribution that no file names is refused."""
     ops = traffic.block_ops(RW50, 1)
     assert np.sum(ops[:, 0] == traffic.READ) == 500 and np.all(ops[:, 1] == 0)
+    closed = traffic.make_schedule(dict(RW50, loop="closed", clients=4), 1,
+                                   256, 3, 2.0, 10)
+    opened = traffic.make_schedule(RW50, 1, 256, 3, 2.0, 10)
+    np.testing.assert_array_equal(closed.kind, opened.kind)
+    assert closed.key.dtype == np.int32 and not closed.due_ns.any()
     with pytest.raises(ValueError):
-        traffic.make_schedule(dict(RW50, loop="closed"), 1, 256, 3, 2.0, 10)
-
+        traffic.make_schedule(dict(RW50, loop="no_such_loop"), 1, 256, 3,
+                              2.0, 10)
+    with pytest.raises(ValueError):
+        traffic.make_schedule(dict(RW50, requestdistribution="no_such"), 1,
+                              256, 3, 2.0, 10)
+    with pytest.raises(ValueError):
+        traffic.make_schedule(dict(RW50, arrivals="bursty"), 1, 256, 3, 2.0,
+                              10)

@@ -1,14 +1,17 @@
 """The one traffic generator: turns a mix file (``bench/traffic/<name>.json``)
 and a seed into the requests of a run.
 
-Every seed gives the same SET of work in another order.  A block of
-``block`` operations holds exactly ``readproportion`` reads, the rest
-updates, spread evenly over the request keys (by largest remainder); the
-seed only shuffles each block and places the arrivals.  Arrivals are a Poisson
-process conditioned on its count: ``rate x seconds`` instants drawn
-uniformly over the window and sorted, so every seed offers the same number
-of requests.  Update rows are drawn from the seed and are
-all distinct, so a value read back names the write that produced it.
+Every seed gives the same SET of work in another order.  The work comes in
+blocks of ``block`` operations, each block's (kind, key) multiset from the
+mix's key distribution (``bench/dists/<requestdistribution>.py``,
+``uniform`` where the mix names none), which draws it from streams that
+the seed does not touch.  The seed only shuffles each block and places
+the arrivals, which the mix's client loop (``bench/loops/<loop>.py``)
+makes: the open loop's are a Poisson process conditioned on its count,
+``rate x seconds`` instants drawn uniformly over the window and sorted, so
+every seed offers the same number of requests.  Update rows are drawn from
+the seed and are all distinct, so a value read back names the write that
+produced it.
 """
 from __future__ import annotations
 
@@ -16,6 +19,8 @@ import dataclasses
 from typing import Dict
 
 import numpy as np
+
+from bench import spec
 
 READ, UPDATE = 0, 1
 ROWS_PER_CHUNK = 1024
@@ -32,16 +37,17 @@ def apportion(total: int, shares: np.ndarray) -> np.ndarray:
     return counts
 
 
-def block_ops(traffic: dict, keys: int) -> np.ndarray:
-    """The (kind, key) multiset of one block, unshuffled: (block, 2)."""
-    n = int(traffic["block"])
-    n_read = int(round(n * float(traffic["readproportion"])))
-    shares = np.full(keys, 1.0 / keys)
-    out = []
-    for kind, count in ((READ, n_read), (UPDATE, n - n_read)):
-        for key, c in enumerate(apportion(count, shares)):
-            out.extend([(kind, key)] * int(c))
-    return np.asarray(out, np.int64).reshape(-1, 2)
+def distribution(traffic: dict, root=spec.ROOT):
+    """The mix's key distribution module."""
+    return spec.load_part("dists", traffic.get("requestdistribution",
+                                               "uniform"), root)
+
+
+def block_ops(traffic: dict, keys: int, index: int = 0,
+              root=spec.ROOT) -> np.ndarray:
+    """The (kind, key) multiset of block ``index``, unshuffled:
+    (block, 2)."""
+    return distribution(traffic, root).block(traffic, keys, index)
 
 
 @dataclasses.dataclass
@@ -75,24 +81,25 @@ class Schedule:
 
 
 def make_schedule(traffic: dict, keys: int, width: int, seed: int,
-                  seconds: float, rate_per_s: float) -> Schedule:
+                  seconds: float, rate_per_s: float,
+                  root=spec.ROOT) -> Schedule:
     """All ``rate_per_s x seconds`` requests of a run, with their due
-    instants."""
+    instants (for a loop that sends on its own clock, the offsets its
+    module gives)."""
     rng = np.random.default_rng([seed, 1])
-    if traffic["loop"] != "open" or traffic["arrivals"] != "poisson":
-        raise ValueError(f"unknown loop {traffic['loop']!r} or arrivals "
-                         f"{traffic.get('arrivals')!r}")
+    dist = distribution(traffic, root)
+    loop = spec.load_part("loops", traffic["loop"], root)
     n = int(round(float(rate_per_s) * seconds))
-    base = block_ops(traffic, keys)
-    blocks = []
-    for _ in range(-(-n // len(base))):
+    blocks, size = [], 0
+    while size < n:
+        base = dist.block(traffic, keys, len(blocks))
         blocks.append(base[rng.permutation(len(base))])
+        size += len(base)
     ops = np.concatenate(blocks)[:n]
-    kind, key = ops[:, 0].astype(np.int8), ops[:, 1].astype(np.int16)
+    kind, key = ops[:, 0].astype(np.int8), ops[:, 1].astype(np.int32)
     upd = kind == UPDATE
     update_id = np.full(n, -1, np.int64)
     update_id[upd] = np.arange(int(upd.sum()))
-    due = np.sort(rng.uniform(0.0, seconds * 1e9, n)).astype(np.int64)
+    due = loop.due_ns(traffic, rng, n, seconds)
     return Schedule(kind=kind, key=key, due_ns=due, update_id=update_id,
                     seed=int(seed), width=int(width))
-
