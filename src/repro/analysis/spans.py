@@ -22,7 +22,7 @@ recorded where the work happens.
 | ``enoki.turn`` | one pump turn of the serving loop (pump, deliver, fail lost) | ``delivered``: futures resolved during the turn |
 | ``enoki.sleep`` | the serving loop's wait for the next deadline or submit | |
 | ``enoki.cycle`` | ``BatchedInvocationEngine._run_cycle`` | ``windows``, ``requests`` |
-| ``enoki.dispatch`` | one chunk of ``BatchedInvocationEngine._exec_group`` (``_exec_chunk``) | ``fn``, ``node``, ``kind`` (``scan`` or ``map``), ``n``, ``bucket`` |
+| ``enoki.dispatch`` | one chunk of ``BatchedInvocationEngine._exec_group`` (``_exec_chunk``) | ``fn``, ``node``, ``kind`` (``scan`` or ``map``), ``n``, ``bucket``, ``keyed_gets`` / ``keyed_sets`` (the handler's ops by a key the request carries, per request) |
 | ``enoki.stage`` | host staging of a chunk's inputs (child of dispatch) | |
 | ``enoki.call`` | the jitted handler call (child of dispatch) | |
 | ``enoki.fetch`` | ``jax.device_get`` of the chunk's outputs (child of dispatch) | |

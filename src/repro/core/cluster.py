@@ -208,8 +208,11 @@ class Cluster:
         self.specs: Dict[str, FunctionSpec] = {}
         self.policies: Dict[str, KeygroupSpec] = {}
         # canonical key->slot layout per keygroup (deploy-time, grows
-        # monotonically) and whether every replica still carries it; an
-        # unaligned keygroup PERMANENTLY uses the O(S^2) fallback merge
+        # monotonically) and whether every replica still carries it.  A
+        # keygroup is aligned from its creation (replicas start blank or
+        # cloned, and a request-keyed write only overwrites its own slot);
+        # only a failed ``_register_keys`` unaligns it, and an unaligned
+        # keygroup PERMANENTLY uses the O(S^2) fallback merge
         self._slot_maps: Dict[str, Dict[int, int]] = {}
         self._aligned: Dict[str, bool] = {}
         self.engine = BatchedInvocationEngine(self)
@@ -218,6 +221,7 @@ class Cluster:
     def create_keygroup(self, spec: KeygroupSpec, nodes: List[str]) -> None:
         self.naming.create_keygroup(spec)
         self.policies[spec.name] = spec
+        self._aligned.setdefault(spec.name, True)
         for n in nodes:
             self._materialise_keygroup(spec, n)
 
@@ -268,6 +272,7 @@ class Cluster:
                 value_width=value_width or spec.codec_width, owner=owner)
             self.policies[kg_name] = kspec
             self.naming.create_keygroup(kspec)
+            self._aligned.setdefault(kg_name, True)
             # store placement depends on policy
             if kspec.policy == ReplicationPolicy.REPLICATED:
                 placement = nodes
@@ -288,10 +293,11 @@ class Cluster:
             else:
                 nd.compute_ms[spec.name] = 0.0
         if spec.keygroups:
-            # canonical slot pre-assignment: the handler's key set is
-            # static (literal strings hashed at trace time), so stamp it
-            # into every replica now — delivery merges then take the
-            # elementwise slot-aligned kernel instead of the O(S^2) probe
+            # canonical slot pre-assignment: the handler's literal key set
+            # is static (strings hashed at trace time), so stamp it into
+            # every replica now — delivery merges then keep the elementwise
+            # slot-aligned kernel instead of the O(S^2) probe.  Keys the
+            # request carries need no slot: their writes never insert
             bh = self.nodes[nodes[0]].batched_handlers[spec.name]
             self._register_keys(spec.keygroups[0],
                                 getattr(bh, "key_hashes", ()))

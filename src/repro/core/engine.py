@@ -267,6 +267,9 @@ class EngineStats(AtomicStats):
     dropped_dead: int = 0           # requests dropped because NO live
                                     # deployment remained (fail-fast under
                                     # the at-most-once contract)
+    keyed_ops: int = 0              # store ops by a key the request
+                                    # carried, over the valid requests
+                                    # dispatched
 
 
 class _NodePool:
@@ -1005,10 +1008,15 @@ class BatchedInvocationEngine:
         # _stage_chunk/_valid_mask) so a warm chunk allocates nothing fresh
         # on the host
         bucket = self._bucket(n)
+        keyed_gets = getattr(bhandler, "keyed_gets", 0)
+        keyed_sets = getattr(bhandler, "keyed_sets", 0)
+        if keyed_gets or keyed_sets:
+            self.stats.inc("keyed_ops", n * (keyed_gets + keyed_sets))
         if span is not None:
             span.set(fn=fn_name, node=node, n=n, bucket=bucket,
                      kind="scan" if kg is not None and not bhandler.read_only
-                     else "map")
+                     else "map", keyed_gets=keyed_gets,
+                     keyed_sets=keyed_sets)
             if pendings is not None:
                 spans.serve(self, [p.ticket for p in pendings], cycle.span,
                             span.id)

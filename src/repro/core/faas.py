@@ -25,8 +25,11 @@ is preserved as::
 invocations pay no setup cost.
 
 Values are encoded by per-keygroup codecs (the arena stores fixed-width
-rows).  Key *strings* are hashed at trace time — they are static, exactly
-like the paper's literal key names.
+rows).  A key is either a *string*, hashed at trace time — static, exactly
+like the paper's literal key names, and pre-assigned a slot at deploy — or
+an int32 *hash the request carries* (per-user or per-session state), traced
+like any other input.  A request-keyed write only overwrites a record its
+key already holds and tells the handler whether it did (``KV.set``).
 """
 from __future__ import annotations
 
@@ -92,6 +95,12 @@ class KV:
     network costs per op for remote placements (CLOUD_CENTRAL/PEER_FETCH),
     which is how the paper's per-op round-trips (§4.1: 4 ops -> +200 ms)
     are accounted.
+
+    A key is a ``str`` (hashed here, at trace time) or an int32 scalar
+    array: a hash the request carries.  Every op enters ``ops``; only
+    literal keys enter ``key_hashes``, and request-keyed ops are counted
+    in ``keyed_gets`` / ``keyed_sets`` (writes: set and delete) instead —
+    all static facts of the trace.
     """
 
     def __init__(self, store: Store, clock: jnp.ndarray, node_id: int,
@@ -101,44 +110,69 @@ class KV:
         self._node_id = node_id
         self._codec = codec
         self.ops: List[Tuple[str, int]] = []   # (kind, payload_bytes)
-        # every key hash the handler touches — static (keys are literal
-        # strings hashed at trace time), so one trace enumerates the full
-        # key set; deploy uses it for canonical slot pre-assignment
+        # every literal key hash the handler touches — static (hashed at
+        # trace time), so one trace enumerates the full literal key set;
+        # deploy uses it for canonical slot pre-assignment
         self.key_hashes: List[int] = []
+        self.keyed_gets = 0
+        self.keyed_sets = 0
+
+    def _hash(self, key, write: bool):
+        """(hash, literal?): a string's static hash, logged; or the
+        request's traced hash, counted as a keyed op."""
+        if isinstance(key, str):
+            h = fnv1a(key)
+            self.key_hashes.append(h)
+            return h, True
+        if write:
+            self.keyed_sets += 1
+        else:
+            self.keyed_gets += 1
+        return jnp.asarray(key, jnp.int32), False
 
     # -- paper API ----------------------------------------------------------
-    def get(self, key: str):
-        h = fnv1a(key)
-        row, length, _, found = kv_get(self._store, h)
+    def get(self, key):
+        h, literal = self._hash(key, write=False)
+        row, length, _, found = kv_get(self._store, h, carried=not literal)
         val = self._codec.decode(row, length)
         nbytes = int(np.dtype(np.float32).itemsize) * self._codec.width
         self.ops.append(("get", nbytes))
-        self.key_hashes.append(h)
         return val, found
 
-    def set(self, key: str, val) -> None:
-        h = fnv1a(key)
+    def set(self, key, val):
+        """Write ``val`` under ``key``.  A literal key upserts and returns
+        nothing; a request key overwrites the record it already holds and
+        returns ``ok`` (False: the arena holds no such key, nothing
+        changed)."""
+        h, literal = self._hash(key, write=True)
         row, length = self._codec.encode(val)
         self._store, self._clock, ok = kv_set(
-            self._store, h, row, length, self._clock, self._node_id)
+            self._store, h, row, length, self._clock, self._node_id,
+            insert=literal)
         self.ops.append(("set", int(row.nbytes)))
-        self.key_hashes.append(h)
+        return None if literal else ok
 
-    def scan(self, keys: Sequence[str]):
-        hashes = [fnv1a(k) for k in keys]
-        vals, lengths, founds = kv_scan(self._store, hashes)
+    def scan(self, keys):
+        """Read several keys: a sequence of strings, or an int32 array of
+        hashes the request carries."""
+        carried = hasattr(keys, "dtype")
+        if carried:
+            hashes = jnp.asarray(keys, jnp.int32)
+            self.keyed_gets += int(hashes.size)
+        else:
+            hashes = [fnv1a(k) for k in keys]
+            self.key_hashes.extend(hashes)
+        vals, lengths, founds = kv_scan(self._store, hashes, carried)
         idx = jnp.arange(vals.shape[1])[None, :]
         vals = jnp.where(idx < lengths[:, None], vals, 0.0)
         self.ops.append(("scan", int(vals.nbytes)))
-        self.key_hashes.extend(hashes)
         return vals, founds
 
-    def delete(self, key: str) -> None:
-        h = fnv1a(key)
+    def delete(self, key) -> None:
+        h, literal = self._hash(key, write=True)
         self._store, self._clock, _ = kv_delete(
-            self._store, h, self._clock, self._node_id)
+            self._store, h, self._clock, self._node_id, carried=not literal)
         self.ops.append(("delete", 0))
-        self.key_hashes.append(h)
 
     # -- plumbing -------------------------------------------------------------
     @property
@@ -240,7 +274,10 @@ def compile_batched_handler(spec: FunctionSpec, node_id: int,
     Returns ``bstep(store, clock, xs, valid, independent=False)`` where
     ``xs`` stacks B request inputs along axis 0 and ``valid`` (B,) bool masks
     bucket padding.  Produces ``(store', clock', ys, op_log)`` with ``ys``
-    stacked per-request outputs.
+    stacked per-request outputs.  Static facts of the deploy-time trace
+    ride on the step: ``op_log``, ``key_hashes`` (literal keys only),
+    ``read_only`` and ``keyed_gets`` / ``keyed_sets`` (ops by a key the
+    request carries, per invocation).
 
     Execution strategy, chosen from the handler's static op trace:
 
@@ -263,6 +300,7 @@ def compile_batched_handler(spec: FunctionSpec, node_id: int,
     codec = VectorCodec(spec.codec_width)
     op_log: List[Tuple[str, int]] = []
     hash_log: List[int] = []
+    keyed: Dict[str, int] = {}
 
     def pure(store: Store, clock: jnp.ndarray, x):
         kv = KV(store, clock, node_id, codec)
@@ -271,6 +309,7 @@ def compile_batched_handler(spec: FunctionSpec, node_id: int,
         op_log.extend(kv.ops)
         hash_log.clear()
         hash_log.extend(kv.key_hashes)
+        keyed.update(gets=kv.keyed_gets, sets=kv.keyed_sets)
         new_store, new_clock = kv.state
         return new_store, new_clock, y
 
@@ -327,6 +366,10 @@ def compile_batched_handler(spec: FunctionSpec, node_id: int,
     bstep.op_log = op_log
     bstep.key_hashes = tuple(dict.fromkeys(hash_log))
     bstep.read_only = read_only
+    # request-keyed ops per invocation (the dispatch span and
+    # ``EngineStats.keyed_ops`` count them)
+    bstep.keyed_gets = keyed["gets"]
+    bstep.keyed_sets = keyed["sets"]
     bstep.example = example_input
     bstep.jit_scan = jit_scan
     bstep.jit_map = jit_map

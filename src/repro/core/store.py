@@ -12,9 +12,11 @@ All operations are pure functions (jit-friendly); the imperative ``kv.get`` /
 ``kv.set`` programming model of the paper's Listing 1 is recovered by the
 ``KV`` handle in ``faas.py`` which threads a ``Store`` through the handler.
 
-Writes that find neither their key nor an empty slot are dropped with
-``ok=False`` (arena overflow) — the FaaS layer surfaces this as an error, the
-same way FReD surfaces storage-backend failures.
+A write returns ``ok``: False when it found neither its key nor an empty
+slot (arena overflow), or, for a write by a key the request carries
+(``insert=False``), when the arena does not hold that key.  A dropped write
+changes nothing.  ``KV.set`` hands the ``ok`` of a request-keyed write back to
+the handler; a literal-key write's ``ok`` is not surfaced (ROADMAP R2).
 
 Thread-safety: a ``Store`` is an immutable NamedTuple of arrays, so every
 function here is safe to call from any thread — "mutation" is producing a
@@ -80,7 +82,8 @@ def store_select(pred, a: Store, b: Store) -> Store:
 # Single-key ops
 # ---------------------------------------------------------------------------
 
-def _locate(store: Store, key_hash) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+def _locate(store: Store, key_hash, carried: bool = False
+            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Canonical slot probe.  Returns ``(slot, found, ok)``:
 
     * ``slot``  — the matching slot when ``found``, else the first empty
@@ -90,6 +93,11 @@ def _locate(store: Store, key_hash) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarr
     * ``ok``    — False only on arena overflow (no match and no empty
       slot); callers drop the write.
 
+    ``carried`` (static) marks a hash the request carries.  Such a hash
+    may be 0, the empty slot's key, which no record holds (``fnv1a``
+    never returns it): it is never ``found``, so it reads as absent and
+    writes nothing.  A literal key traces exactly as before.
+
     Slot-alignment contract: when a keygroup's keys were pre-assigned at
     deploy time (``store_assign_slots`` stamps each key into its
     canonical slot as a version-0 tombstone), the argmax probe lands on
@@ -97,13 +105,16 @@ def _locate(store: Store, key_hash) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarr
     elementwise merge path (``merge_stores_aligned``) relies on."""
     match = store.keys == key_hash
     found = match.any()
+    if carried:
+        found = found & (key_hash != 0)
     empty = store.keys == 0
     slot = jnp.where(found, jnp.argmax(match), jnp.argmax(empty))
     ok = found | empty.any()
     return slot, found, ok
 
 
-def kv_get(store: Store, key_hash) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+def kv_get(store: Store, key_hash, carried: bool = False
+           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Returns (value_row, length, version, found).
 
     Tombstone-read contract: ``_locate``'s ``found`` means the key
@@ -111,8 +122,8 @@ def kv_get(store: Store, key_hash) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarra
     tombstoned key (length < 0, written by ``kv_delete`` or by the
     deploy-time slot pre-assignment) reads as absent: zero value, zero
     length, found=False.  Its version still reads through so causal
-    consumers can observe the delete."""
-    slot, found, _ = _locate(store, key_hash)
+    consumers can observe the delete.  ``carried``: as in ``_locate``."""
+    slot, found, _ = _locate(store, key_hash, carried)
     live = found & (store.lengths[slot] >= 0)
     value = jnp.where(live, store.values[slot], jnp.zeros_like(store.values[slot]))
     length = jnp.where(live, store.lengths[slot], 0)
@@ -120,17 +131,25 @@ def kv_get(store: Store, key_hash) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarra
     return value, length, version, live
 
 
-def kv_set(store: Store, key_hash, value_row, length, clock, node_id
-           ) -> Tuple[Store, jnp.ndarray, jnp.ndarray]:
-    """Write (upsert).  Returns (store', new_clock, ok).
+def kv_set(store: Store, key_hash, value_row, length, clock, node_id,
+           insert: bool = True) -> Tuple[Store, jnp.ndarray, jnp.ndarray]:
+    """Write.  Returns (store', new_clock, ok).
 
     The node's lamport clock advances past everything this replica has seen
     (max of vv) so versions from causally-later writes always dominate.
+
+    ``insert`` (static) decides what a key the arena does not hold does:
+    True upserts it into the first empty slot; False (a key the request
+    carries) writes nothing — no slot, value, clock or version-vector
+    entry moves — and returns ``ok=False``.  Such a write only overwrites
+    the slot its key already occupies (live or tombstoned), so two
+    replicas never claim one empty slot for different keys and slot
+    alignment holds; a hash of 0 is absent (``_locate``'s ``carried``).
     """
-    slot, _, ok = _locate(store, key_hash)
+    slot, found, ok = _locate(store, key_hash, carried=not insert)
     new_clock = jnp.maximum(clock, store.vv.max()) + 1
     version = pack_version(new_clock, node_id)
-    write = ok  # drop on arena overflow
+    write = ok if insert else found  # drop on overflow / on an absent key
 
     def apply(s: Store) -> Store:
         return Store(
@@ -145,10 +164,12 @@ def kv_set(store: Store, key_hash, value_row, length, clock, node_id
     return new_store, jnp.where(write, new_clock, clock), write
 
 
-def kv_delete(store: Store, key_hash, clock, node_id) -> Tuple[Store, jnp.ndarray, jnp.ndarray]:
-    """Tombstone write (length = -1) so deletes replicate like updates."""
+def kv_delete(store: Store, key_hash, clock, node_id, carried: bool = False
+              ) -> Tuple[Store, jnp.ndarray, jnp.ndarray]:
+    """Tombstone write (length = -1) so deletes replicate like updates.
+    ``carried``: as in ``_locate``."""
     zero = jnp.zeros((store.value_width,), store.values.dtype)
-    slot, found, _ = _locate(store, key_hash)
+    slot, found, _ = _locate(store, key_hash, carried)
     new_clock = jnp.maximum(clock, store.vv.max()) + 1
     version = pack_version(new_clock, node_id)
 
@@ -165,10 +186,12 @@ def kv_delete(store: Store, key_hash, clock, node_id) -> Tuple[Store, jnp.ndarra
     return new_store, jnp.where(found, new_clock, clock), found
 
 
-def kv_scan(store: Store, key_hashes) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Vectorised multi-get: (values (K,V), lengths (K,), found (K,))."""
+def kv_scan(store: Store, key_hashes, carried: bool = False
+            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Vectorised multi-get: (values (K,V), lengths (K,), found (K,)).
+    ``carried``: as in ``_locate``."""
     def one(h):
-        v, l, _, f = kv_get(store, h)
+        v, l, _, f = kv_get(store, h, carried)
         return v, l, f
 
     return jax.vmap(one)(jnp.asarray(key_hashes, jnp.int32))

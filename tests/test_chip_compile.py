@@ -123,3 +123,51 @@ def test_handler_compiles_at_smoke_arena(one_chip, handler, bucket,
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < HBM_BYTES // 2, mem
+
+
+YCSB_ARENA = (262_144, 250)         # 10 fields x 25 float32 a record
+
+
+def _keyed_read(kv, h):
+    record, _ = kv.get(h)
+    return record
+
+
+def _field_update(kv, x):
+    h, f, payload = x
+    record, _ = kv.get(h)
+    record = jax.lax.dynamic_update_slice(record, payload, (f * 25,))
+    ok = kv.set(h, record)
+    return jnp.where(ok, kv.state[1], -1)
+
+
+@pytest.mark.parametrize("handler,bucket", [(_keyed_read, 256),
+                                            (_field_update, 256)])
+def test_request_keyed_handler_compiles_at_ycsb_arena(one_chip, handler,
+                                                      bucket):
+    """Handlers that take the key hash from the request: the read map
+    (``jit_map``) and the one-field read-modify-write fold (``jit_scan``)
+    at the widest bucket compile at 262,144 records of 250 floats and fit
+    HBM, and the aligned merge of that arena runs the Pallas kernel."""
+    slots, width = YCSB_ARENA
+    spec = FunctionSpec(name=f"chip_{handler.__name__}", handler=handler,
+                        keygroups=["chipkg"], codec_width=width)
+    i32 = jnp.zeros((), jnp.int32)
+    example = (i32 if handler is _keyed_read
+               else (i32, i32, jnp.zeros((25,), jnp.float32)))
+    bh = compile_batched_handler(spec, 0, example)
+    store = _arena(slots, width, one_chip)
+    clock = _spec((), jnp.int32, one_chip)
+    xs = jax.tree.map(lambda a: _spec((bucket,) + a.shape, a.dtype,
+                                      one_chip), example)
+    if bh.read_only:
+        lowered = bh.jit_map.lower(store, clock, xs)
+    else:
+        lowered = bh.jit_scan.lower(store, clock, xs,
+                                    _spec((bucket,), jnp.bool_, one_chip))
+    mem = lowered.compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < HBM_BYTES // 2, mem
+    compiled = merge_many_fn(True).lower(store, (store,) * 4).compile()
+    assert "tpu_custom_call" in compiled.as_text()
